@@ -38,7 +38,8 @@ Everything lands in machine-readable ``BENCH_ooc.json`` (per-config
 steady-state wall times, streaming speedups, picked plans) so CI can
 archive the perf trajectory across PRs. ``--smoke`` runs a tiny config
 (CI keeps the OOC path and the README examples honest without burning
-minutes).
+minutes). The wall times are taken on whatever backend runs the script —
+the committed ``BENCH_ooc.json`` is CPU smoke output, not a device metric.
 """
 from __future__ import annotations
 

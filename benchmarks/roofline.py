@@ -18,7 +18,8 @@ HLO analyzer on a real lowered superstep (``hlo_calibrate``) for both
 implementations; ``--smoke`` skips the compile-heavy cross-check.
 
 Writes ``BENCH_roofline.json`` (schema ``roofline/v1``); ``--validate
-PATH`` re-opens an artifact and checks the schema (the CI gate).
+PATH`` re-opens an artifact and checks the schema (the CI gate). Its
+numbers are cost-model estimates computed on the CPU, not device metrics.
 """
 from __future__ import annotations
 

@@ -59,9 +59,8 @@ def plan_gather_layout(plan: PhysicalPlan, vert: VertexRel):
     layout serves a whole run; recompute only on plan switches."""
     if not kbackend.wants_edge_layout(plan):
         return None
-    perm, tile_row = kbackend.plan_edge_layout(
-        np.asarray(vert.edge_src), vert.capacity)
-    return jnp.asarray(perm), jnp.asarray(tile_row)
+    return tuple(jnp.asarray(a) for a in kbackend.plan_edge_layout(
+        np.asarray(vert.edge_src), vert.capacity))
 
 
 @dataclass
@@ -76,26 +75,24 @@ class RunResult:
 
 
 def _resolve_plan(vert, program, plan: PlanArg, *, adaptive: bool,
-                  ec: Optional[EngineConfig] = None,
                   auto_config=None, auto_space=None, graph_stats=None,
                   machine=None, obs0=None):
     """plan="auto" -> (cost-model-chosen plan, AdaptiveController|None).
     `graph_stats` short-circuits the vertex scan (the OOC resume path
     rebuilds the counts page-at-a-time and never holds a VertexRel).
-    `machine` overrides the emulated-vs-default machine-model choice
-    (the sharded driver picks per backend); `obs0` seeds the initial
-    observation (sharded=True / n_workers for the network axis)."""
+    `machine` defaults to the ``MACHINES`` entry of the device JAX runs
+    on; `obs0` seeds the initial observation (sharded=True / n_workers
+    for the network axis)."""
     if isinstance(plan, PhysicalPlan):
         return plan, None
     if plan != "auto":
         raise ValueError(f"plan must be a PhysicalPlan or 'auto', "
                          f"got {plan!r}")
-    from repro.planner import (DEFAULT_MACHINE, EMULATED_MACHINE,
-                               AdaptiveConfig, resolve_auto_plan)
-    emulated = ec is None or ec.axis_name is None
+    from repro.planner import (AdaptiveConfig, machine_for,
+                               resolve_auto_plan)
     config = auto_config or AdaptiveConfig()
     if machine is None:
-        machine = EMULATED_MACHINE if emulated else DEFAULT_MACHINE
+        machine = machine_for()
     if config.calibrate:
         # one-shot startup calibration (opt-in): lower a probe superstep
         # per backend and refit the analytic cost constants against the
@@ -133,22 +130,29 @@ def init_vertex_values(vert: VertexRel, program: VertexProgram,
 
 def grow_overflowed(ec: EngineConfig, delta, *,
                     vertex_capacity: int = 0) -> EngineConfig:
-    """Double only the capacities whose per-source overflow counter grew
-    (`delta` = the GlobalState.overflow increase of the failed step).
+    """Grow only the capacities whose per-source overflow counter grew
+    (`delta` = the GlobalState.overflow increase of the failed step), to
+    at least double and at least what the dropped tuples need: every
+    partition dropped no more than the total, so old + total always fits
+    the redo, and one overflow costs one recompile, not one per doubling.
     Edge-stream overflow is attributed to the frontier: the edge
     compaction capacity is derived from frontier_cap (EF = 8 *
     frontier_cap in gen_messages). A frontier_cap of 0 (the "Np/2"
     EngineConfig default) is resolved against `vertex_capacity` first so
-    the doubling cannot wedge at 0."""
+    the growth cannot wedge at 0."""
     delta = np.asarray(delta)
     kw = {}
     if delta[OVF_BUCKET] > 0:
-        kw["bucket_cap"] = ec.bucket_cap * 2
+        kw["bucket_cap"] = max(ec.bucket_cap * 2,
+                               ec.bucket_cap + int(delta[OVF_BUCKET]))
     if delta[OVF_FRONTIER] > 0 or delta[OVF_EDGE] > 0:
         cur = ec.frontier_cap or max(vertex_capacity // 2, 1)
-        kw["frontier_cap"] = cur * 2
+        edges = max(cur * 8, 64) + int(delta[OVF_EDGE])
+        kw["frontier_cap"] = max(cur * 2, cur + int(delta[OVF_FRONTIER]),
+                                 -(-edges // 8))
     if delta[OVF_MUTATION] > 0:
-        kw["mutation_cap"] = ec.mutation_cap * 2
+        kw["mutation_cap"] = max(ec.mutation_cap * 2,
+                                 ec.mutation_cap + int(delta[OVF_MUTATION]))
     return dataclasses.replace(ec, **kw)
 
 
@@ -159,7 +163,7 @@ def run_jit(vert: VertexRel, program: VertexProgram,
             kernel_impl: Optional[str] = None) -> RunResult:
     t0 = time.time()
     # "auto" resolves once up front (whole-loop jit: no mid-run switching)
-    plan, _ = _resolve_plan(vert, program, plan, adaptive=False, ec=ec)
+    plan, _ = _resolve_plan(vert, program, plan, adaptive=False)
     if kernel_impl is not None:
         plan = dataclasses.replace(plan, kernel_impl=kernel_impl)
     ec = ec or default_engine_config(vert, program, plan)
@@ -259,7 +263,7 @@ def run_host(vert: VertexRel, program: VertexProgram,
         i0 = int(rgs.superstep)
     plan, auto_space = apply_kernel_impl(plan, kernel_impl, auto_space)
     plan, controller = _resolve_plan(vert, program, plan, adaptive=True,
-                                     ec=ec, auto_config=auto_config,
+                                     auto_config=auto_config,
                                      auto_space=auto_space)
     ec = ec or default_engine_config(vert, program, plan)
     if rmsg is not None and rmsg.capacity > ec.n_parts * ec.bucket_cap:
@@ -270,14 +274,13 @@ def run_host(vert: VertexRel, program: VertexProgram,
     if explain.enabled():
         # plan-audit ledger: bind the run context so each superstep's
         # stats record can be re-priced under the in-effect plan
-        from repro.planner.cost import DEFAULT_MACHINE, EMULATED_MACHINE
+        from repro.planner.cost import machine_for
         explain.attach(
             program, vert=vert,
             g=controller.g if controller is not None else None,
             plan=plan,
-            machine=(controller.machine if controller is not None else
-                     (EMULATED_MACHINE if ec.axis_name is None
-                      else DEFAULT_MACHINE)),
+            machine=(controller.machine if controller is not None
+                     else machine_for()),
             space_kw=auto_space)
     step = jax.jit(make_superstep(program, plan, ec))
     layout = plan_gather_layout(plan, vert)

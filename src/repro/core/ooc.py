@@ -556,7 +556,7 @@ def run_out_of_core(vert: Optional[VertexRel], program: VertexProgram,
                 auto_space.setdefault("kernel_impls", (kernel_impl,))
         plan, controller = _resolve_plan(
             shape_vert if resume_from is None else None, program, plan,
-            adaptive=True, ec=ec, auto_config=auto_config,
+            adaptive=True, auto_config=auto_config,
             auto_space=_OOC_AUTO_SPACE if auto_space is None
             else auto_space, graph_stats=graph_stats)
         if saved_plan is not None:
@@ -606,7 +606,7 @@ def run_out_of_core(vert: Optional[VertexRel], program: VertexProgram,
             # plan-audit ledger: the shadow auditor re-prices the
             # in-effect plan per superstep (static resumes without
             # graph statistics stay decision-log-only)
-            from repro.planner.cost import EMULATED_MACHINE
+            from repro.planner.cost import machine_for
             explain.attach(
                 program,
                 vert=shape_vert if resume_from is None else None,
@@ -614,7 +614,7 @@ def run_out_of_core(vert: Optional[VertexRel], program: VertexProgram,
                    else graph_stats),
                 plan=plan,
                 machine=(controller.machine if controller is not None
-                         else EMULATED_MACHINE),
+                         else machine_for()),
                 space_kw=(_OOC_AUTO_SPACE if auto_space is None
                           else auto_space))
         if memwatch.enabled():
@@ -639,9 +639,9 @@ def run_out_of_core(vert: Optional[VertexRel], program: VertexProgram,
                 return None
             lay = gather_layouts.get(q)
             if lay is None:
-                perm, tile = kbackend.plan_edge_layout(
-                    store.read("edge_src", q), Np)
-                lay = (jax.device_put(perm), jax.device_put(tile))
+                lay = tuple(jax.device_put(a) for a in
+                            kbackend.plan_edge_layout(
+                                store.read("edge_src", q), Np))
                 gather_layouts[q] = lay
             return lay
 

@@ -70,32 +70,15 @@ _MSG_W = lambda D: (1 + D) * 4 + 1   # dst + payload + valid wire bytes
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions (same fallbacks as pregel_run)."""
-    try:
-        from jax import shard_map
-    except ImportError:      # JAX < 0.6 keeps shard_map in experimental
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    """The driver's shard_map: specs are written by hand, so the
+    replication check is off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:        # older shard_map spells check_vma check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def _lead_spec(axes):
     """Leading-axis sharding spec builder: dim 0 over the mesh axes."""
     return lambda x: PSpec(*([axes] + [None] * (len(x.shape) - 1)))
-
-
-def _sharded_machine():
-    """Machine model for the sharded driver's planner: roofline constants
-    for the backend we actually run on (the CPU fake-device mesh prices
-    like the emulated machine — same memory system, ms-class dispatch
-    latency per exchange stage), TPU-class otherwise."""
-    from repro.planner import DEFAULT_MACHINE, EMULATED_MACHINE
-    return (EMULATED_MACHINE if jax.default_backend() == "cpu"
-            else DEFAULT_MACHINE)
 
 
 def _exchange_wire_bytes(P: int, n_parts: int, C: int, D: int,
@@ -202,7 +185,9 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
     P = vert.num_partitions
     if P % N:
         raise ValueError(f"n_partitions {P} must divide over {N} devices")
-    machine = machine or _sharded_machine()
+    if machine is None:
+        from repro.planner import machine_for
+        machine = machine_for()
 
     if recover:
         from repro.runtime.checkpoint import latest_checkpoint
@@ -334,8 +319,9 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
         msg = put_lead(_regrow_msgs(rmsg, ec))
     else:
         gs = init_gs(program.agg_dims)
-        vert = init_vertex_values(vert, program, gs)
-        vert = put_lead(vert)
+        # place first: each worker initializes its own partitions
+        # instead of the whole graph landing on the first device
+        vert = put_lead(init_vertex_values(put_lead(vert), program, gs))
         gs = put_rep(gs)
         msg = put_lead(empty_msgs(P, ec.n_parts * ec.bucket_cap,
                                   program.msg_dims))

@@ -151,9 +151,7 @@ def make_superstep(program: VertexProgram, plan: PhysicalPlan,
         idx = jnp.zeros((), jnp.int32)
         n_shards = 1
         for a in ec.axis_name:
-            # psum of a static 1 folds to the static axis size (0.4.x
-            # has no jax.lax.axis_size)
-            sz = jax.lax.psum(1, a)
+            sz = jax.lax.axis_size(a)
             idx = idx * sz + jax.lax.axis_index(a)
             n_shards *= sz
         ids = idx * (n_parts // n_shards) + \
@@ -260,33 +258,32 @@ def make_superstep(program: VertexProgram, plan: PhysicalPlan,
             src_val = kbackend.edge_gather_values(
                 value_new, edge_src, layout, impl_r=impl_r)
         else:
-            src_val = jnp.take_along_axis(value_new, esl[..., None]
-                                          .repeat(value_new.shape[-1], -1),
-                                          axis=1)
+            # one (P, Ep) gather per value channel: a (P, Ep, V) gather
+            # index would pad its narrow minor dimension to a full tile
+            src_val = jnp.stack(
+                [jnp.take_along_axis(value_new[..., c], esl, axis=1)
+                 for c in range(value_new.shape[-1])], axis=-1)
         payload = program.send(src_vid, src_val, edge_val, edge_dst, gs)
         return edge_dst, payload, egate, ovf_edges
 
     def sender_combine(dst, payload, valid):
         if named_comb:
-            # segment_combine kernel path: single-pass blocked segmented
-            # fold over the dst-sorted stream. BOTH impls run the same
-            # blocked reduction order ("ref" = jnp re-execution of the
+            # segment_combine kernel path: one stable sort of every
+            # partition's (key, payload channels) by destination, then one
+            # tiled segmented fold over all partitions. BOTH impls run the
+            # same tiled reduction order ("ref" = jnp re-execution of the
             # kernel's tile network) so kernel_impl="ref" and ="pallas"
-            # are bit-for-bit identical even for float sums. pallas_call
-            # must not be vmapped (the batching rule would regrid the
-            # sequential tile carry), so partitions unroll — P_local is
-            # small and static.
+            # are bit-for-bit identical even for float sums.
             big = jnp.iinfo(jnp.int32).max
-            outs = []
-            for p in range(dst.shape[0]):
-                key = jnp.where(valid[p], dst[p], big)
-                order = jnp.argsort(key)
-                ks, ps, vs = key[order], payload[p][order], valid[p][order]
-                folded, is_last = kbackend.sorted_segment_fold(
-                    ks, ps, vs, program.combine_op, impl_r=impl_r)
-                outs.append((jnp.where(is_last, ks, -1), folded, is_last))
-            stack = lambda i: jnp.stack([o[i] for o in outs])
-            return stack(0), stack(1), stack(2)
+            key = jnp.where(valid, dst, big)
+            ks, *cols = jax.lax.sort(
+                (key, *[payload[..., d] for d in range(payload.shape[-1])]),
+                dimension=1, num_keys=1, is_stable=True)
+            folded, is_last = kbackend.sorted_segment_fold(
+                ks, jnp.stack(cols, axis=1), ks != big, program.combine_op,
+                impl_r=impl_r)
+            return (jnp.where(is_last, ks, -1), jnp.moveaxis(folded, 1, 2),
+                    is_last)
 
         def per_part(d, p, v):
             ks, folded, is_last = groupby.sort_combine(

@@ -11,6 +11,7 @@ gather, and the blocked segmented fold — each shaped so that
 from __future__ import annotations
 
 import os
+import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -20,12 +21,20 @@ import numpy as np
 VALID_IMPLS = ("auto", "ref", "pallas", "pallas_tpu")
 ENV_VAR = "REPRO_KERNEL_IMPL"
 
-# Engine block sizes, shared with the planner's cost model. BM is the
-# edge-stream tile; BR is the gather's row-block (the one-hot matmul
-# contraction width).
-GATHER_BLOCK_M = 512
+# Engine block sizes, shared with the planner's cost model. The gather
+# tile is GATHER_BLOCK_M edge slots (8 sublanes x 128 lanes) over a row
+# block of GATHER_BLOCK_R value rows (the one-hot matmul contraction
+# width). The combine tile is up to COMBINE_BLOCK_ROWS x 128 stream rows.
+GATHER_BLOCK_M = 1024
 GATHER_BLOCK_R = 256
-COMBINE_BLOCK_M = 512
+COMBINE_BLOCK_ROWS = 256
+
+
+def combine_block(M: int) -> int:
+    """Combine tile size (stream rows) for a stream of M rows: whole
+    (8, 128) vreg rows, at most COMBINE_BLOCK_ROWS of them."""
+    rows = -(-max(M, 1) // 128)
+    return 128 * min(COMBINE_BLOCK_ROWS, -(-rows // 8) * 8)
 
 
 def on_tpu() -> bool:
@@ -49,6 +58,9 @@ def resolve(impl: str, *, tpu: Optional[bool] = None) -> str:
         if env not in VALID_IMPLS:
             raise ValueError(
                 f"{ENV_VAR}={env!r}: expected one of {VALID_IMPLS}")
+        if env != impl:
+            warnings.warn(f"{ENV_VAR}={env} replaces kernel_impl={impl!r}",
+                          stacklevel=2)
         impl = env
     if impl not in VALID_IMPLS:
         raise ValueError(
@@ -71,7 +83,8 @@ def wants_edge_layout(plan) -> bool:
     return resolve(plan.kernel_impl) != "ref" and plan.join == "full_outer"
 
 
-def plan_edge_layout(edge_src, n_rows: int) -> Tuple[np.ndarray, np.ndarray]:
+def plan_edge_layout(edge_src, n_rows: int) \
+        -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Host-side gather layout for a (P, Ep) edge_src block over (P, n_rows)
     value rows. Partitions are flattened into ONE (P*Ep,) edge stream over
     P*n_rows rows — ``pallas_call`` must not be vmapped (the batching rule
@@ -90,9 +103,19 @@ def plan_edge_layout(edge_src, n_rows: int) -> Tuple[np.ndarray, np.ndarray]:
                              block_r=GATHER_BLOCK_R)
 
 
+def edge_layout_shapes(P: int, Ep: int, n_rows: int):
+    """Shapes of ``plan_edge_layout``'s result for a (P, Ep) edge block
+    over (P, n_rows) value rows, without planning it."""
+    from repro.kernels.csr_spmv.ops import layout_capacity
+    cap = layout_capacity(P * Ep, P * n_rows, block_m=GATHER_BLOCK_M,
+                          block_r=GATHER_BLOCK_R)
+    return tuple(jax.ShapeDtypeStruct(n, jnp.int32)
+                 for n in ((cap,), (P * Ep,), (cap // GATHER_BLOCK_M,)))
+
+
 def edge_gather_values(values, edge_src, layout, *, impl_r: str):
     """Gather ``values[p, edge_src[p, e]]`` per edge via the csr_spmv
-    one-hot-MXU-matmul kernel. values: (P, Np, V); edge_src: (P, Ep),
+    one-hot matmul kernel. values: (P, Np, V); edge_src: (P, Ep),
     -1 = invalid; layout from ``plan_edge_layout``. Returns (P, Ep, V);
     invalid lanes read 0.0 (masked downstream by the edge gate, exactly
     like the clip-gather's arbitrary row-0 reads on the jnp path).
@@ -100,60 +123,63 @@ def edge_gather_values(values, edge_src, layout, *, impl_r: str):
     Bit-for-bit discipline: a finite value survives the one-hot matmul
     exactly (one 1.0*x product plus exact 0.0 additions; -0.0 may
     normalize to +0.0, which still compares equal). Non-finite values
-    would be destroyed by the 0*x products (0*inf = nan), so they ride a
-    side "class" channel (0 finite / 1 +inf / 2 -inf / 3 nan) and are
-    re-materialized after the gather."""
-    from repro.kernels.csr_spmv import ops as csr_ops
+    would be destroyed by the 0*x products (0*inf = nan), so they ride
+    one extra "class" channel (2 bits per value channel: 0 finite, 1 +inf,
+    2 -inf, 3 nan) and are re-materialized after the gather. The table
+    and the result stay channel-major, so no array carries a narrow
+    minor dimension."""
+    from repro.kernels.csr_spmv.ops import gather_channels
     P, Np, V = values.shape
     Ep = edge_src.shape[1]
-    vals = values.reshape(P * Np, V)
-    finite = jnp.isfinite(vals)
-    cls = jnp.where(finite, 0.0,
-                    jnp.where(jnp.isnan(vals), 3.0,
-                              jnp.where(vals > 0, 1.0, 2.0)))
-    packed = jnp.concatenate([jnp.where(finite, vals, 0.0), cls], axis=-1)
-    off = (jnp.arange(P, dtype=jnp.int32) * Np)[:, None]
-    flat_src = jnp.where(edge_src >= 0, edge_src + off, -1).reshape(-1)
-    ones = jnp.ones(flat_src.shape, jnp.float32)
-    out = csr_ops.edge_gather(packed, flat_src, ones, layout=layout,
-                              impl=impl_r, block_m=GATHER_BLOCK_M,
-                              block_r=GATHER_BLOCK_R)
-    g, c = out[:, :V], out[:, V:]
-    g = jnp.where(c == 1.0, jnp.inf,
-                  jnp.where(c == 2.0, -jnp.inf,
-                            jnp.where(c == 3.0, jnp.nan, g)))
-    return g.reshape(P, Ep, V)
+    chans = [values[..., c].reshape(-1) for c in range(V)]
+    cls = sum(jnp.where(jnp.isfinite(x), 0.0,
+                        jnp.where(jnp.isnan(x), 3.0,
+                                  jnp.where(x > 0, 1.0, 2.0))) * 4.0 ** c
+              for c, x in enumerate(chans))
+    table = jnp.stack([jnp.where(jnp.isfinite(x), x, 0.0) for x in chans]
+                      + [cls])
+    g = gather_channels(table, layout, interpret=(impl_r != "pallas_tpu"),
+                        block_m=GATHER_BLOCK_M, block_r=GATHER_BLOCK_R)
+    code = g[V].astype(jnp.int32)
+    out = []
+    for c in range(V):
+        k = (code >> (2 * c)) & 3
+        out.append(jnp.where(k == 1, jnp.inf,
+                             jnp.where(k == 2, -jnp.inf,
+                                       jnp.where(k == 3, jnp.nan, g[c])))
+                   .reshape(P, Ep))
+    return jnp.stack(out, axis=-1)
 
 
 def sorted_segment_fold(keys, payload, valid, op: str, *, impl_r: str):
-    """Inclusive segmented fold over a key-sorted stream — the engine's
-    sender-combine reduction. keys: (M,) ascending, invalid rows keyed
-    int32.max at the tail; payload: (M, D). Returns (folded (M, D),
-    is_last (M,) — already masked by valid).
+    """Inclusive segmented fold over each partition's key-sorted stream —
+    the engine's sender-combine reduction. keys: (P, M), ascending per
+    partition; payload: (P, D, M) channel-major; valid: (P, M). Returns
+    (folded (P, D, M), is_last (P, M) — already masked by valid).
 
-    Both impls execute the SAME blocked reduction order (per-tile
-    Hillis-Steele doubling + sequential tile carry): "ref" through
-    ``segment_combine_blocked`` jnp, "pallas" through the Pallas kernel
-    (interpret mode off-TPU). M is padded to a tile multiple here so the
-    kernel never sees a ragged tile — one code path, bit-for-bit parity
-    for float sums included."""
-    from repro.kernels.segment_combine.ref import segment_combine_blocked
-    from repro.kernels.segment_combine.segment_combine import \
-        segment_combine_pallas
-    M, D = payload.shape
-    BM = min(COMBINE_BLOCK_M, M)
-    pad = (-M) % BM
-    if pad:
-        big = jnp.iinfo(jnp.int32).max
-        keys = jnp.concatenate([keys, jnp.full((pad,), big, keys.dtype)])
-        payload = jnp.concatenate(
-            [payload, jnp.zeros((pad, D), payload.dtype)])
-        valid = jnp.concatenate([valid, jnp.zeros((pad,), bool)])
+    Both impls execute the SAME tiled reduction order: "ref" through the
+    jnp re-execution ``fold_lane_dense_ref``, "pallas" through the Pallas
+    kernel (interpret mode off-TPU). M is padded to a tile multiple here
+    so the kernel never sees a ragged tile — one code path, bit-for-bit
+    parity for float sums included. One call folds every partition: the
+    kernel's carry resets at each partition's first tile."""
+    from repro.kernels.segment_combine.ref import fold_lane_dense_ref
+    from repro.kernels.segment_combine.segment_combine import (
+        IDENT, fold_lane_dense)
+    P, D, M = payload.shape
+    bm = combine_block(M)
+    pad = (-M) % bm
+    big = jnp.iinfo(jnp.int32).max
+    key = jnp.where(valid, keys, big)
+    kp = jnp.pad(key, ((0, 0), (0, pad)), constant_values=big)
+    pp = jnp.pad(jnp.where(valid[:, None], payload,
+                           IDENT[op]).astype(jnp.float32),
+                 ((0, 0), (0, 0), (0, pad)), constant_values=IDENT[op])
     if impl_r == "ref":
-        folded, is_last = segment_combine_blocked(keys, payload, valid, op,
-                                                  block_m=BM)
+        folded = fold_lane_dense_ref(kp, pp, op, block_m=bm)
     else:
-        folded, is_last = segment_combine_pallas(
-            keys, payload, valid, op, block_m=BM,
-            interpret=(impl_r != "pallas_tpu"))
-    return folded[:M], is_last[:M]
+        folded = fold_lane_dense(kp, pp, op, block_m=bm,
+                                 interpret=(impl_r != "pallas_tpu"))
+    is_last = jnp.concatenate(
+        [key[:, 1:] != key[:, :-1], jnp.ones((P, 1), bool)], axis=1) & valid
+    return folded[:, :, :M], is_last

@@ -1,11 +1,18 @@
 """Pallas TPU kernel: row-blocked edge gather (CSR message generation).
 
-Edges are src-sorted; the host (ops.py) pads each BR-row block's edge range
-to a BM multiple so every edge tile touches exactly one row block. The
-tile -> row-block map arrives via scalar prefetch and selects the vertex
-value block in the BlockSpec index_map. Inside the kernel the gather is a
-ONE-HOT MATMUL — (BM x BR) @ (BR x V) on the MXU — the TPU-native answer
-to random access (no scalar gathers in the inner loop).
+Edges are grouped by the row block of their source; the host (ops.py)
+pads each block's edge range to a multiple of the tile, so every tile of
+BM edge slots reads exactly one block of BR value rows. The tile -> row
+block map arrives via scalar prefetch and selects the value block in the
+BlockSpec index_map.
+
+Everything is lane-dense: the edge slots of a tile are an (S, 128) block
+of slot rows, the value table is channel-major (C8, N) with the channels
+padded to 8 sublanes, and the output is channel-major (C, slots / 128,
+128). For each row of 128 slots the gather is a one-hot matmul on the MXU,
+(C8, BR) @ (BR, 128): the transposed orientation puts the edge slots on
+the lanes of the result. HIGHEST precision makes the f32 product exact,
+so a finite value is reproduced bit for bit.
 """
 from __future__ import annotations
 
@@ -16,47 +23,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _kernel(tile_row_ref, src_ref, val_ref, values_ref, out_ref, *,
-            block_r: int):
-    t = pl.program_id(0)
-    r0 = tile_row_ref[t] * block_r
-    src = src_ref[:]                       # (BM, 1) int32, -1 pads
-    ev = val_ref[:].astype(jnp.float32)    # (BM, 1)
-    vals = values_ref[0].astype(jnp.float32)  # (BR, V)
-    local = src[:, 0] - r0                 # (BM,)
-    ok = (src[:, 0] >= 0)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32,
-                                       (src.shape[0], block_r), 1)
-              == local[:, None]) & ok[:, None]
-    g = jax.lax.dot_general(onehot.astype(jnp.float32), vals,
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    out_ref[:] = g * ev
+LANES = 128
 
 
-def edge_gather_pallas(values: jax.Array, edge_src: jax.Array,
-                       edge_val: jax.Array, tile_row: jax.Array, *,
-                       block_m: int = 512, block_r: int = 256,
+def _kernel(tile_row_ref, src_ref, tab_ref, out_ref, *, block_r: int,
+            n_chan: int):
+    r0 = tile_row_ref[pl.program_id(0)] * block_r
+    tab = tab_ref[...]                                   # (C8, BR)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_r, LANES), 0)
+    for s in range(src_ref.shape[0]):
+        local = src_ref[s:s + 1, :] - r0                 # (1, 128); pads < 0
+        onehot = jnp.where(rows == local, 1.0, 0.0)      # (BR, 128)
+        res = jax.lax.dot_general(
+            tab, onehot, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)          # (C8, 128)
+        for c in range(n_chan):
+            out_ref[c, s:s + 1, :] = res[c:c + 1, :]
+
+
+def edge_gather_pallas(table: jax.Array, slot_src: jax.Array,
+                       tile_row: jax.Array, n_chan: int, *,
+                       block_m: int = 1024, block_r: int = 256,
                        interpret: bool = True):
-    """values: (N, V) (N a multiple of block_r); edge_src: (Ep,) src-sorted,
-    padded so tile i only touches rows of block tile_row[i]. -> (Ep, V)."""
-    Ep = edge_src.shape[0]
-    N, V = values.shape
-    BM = min(block_m, Ep)
-    n_tiles = pl.cdiv(Ep, BM)
+    """table: (C8, N) channel-major, C8 a multiple of 8 and N of block_r;
+    slot_src: (n_tiles * block_m,) int32 value row per edge slot, -1 pad,
+    tile i only touching rows of block tile_row[i].
+    -> (n_chan, n_tiles * block_m) gathered channels (0.0 at pads)."""
+    n_slots = slot_src.shape[0]
+    C8, N = table.shape
+    S = block_m // LANES
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((BM, 1), lambda i, tr: (i, 0)),
-                  pl.BlockSpec((BM, 1), lambda i, tr: (i, 0)),
-                  pl.BlockSpec((1, block_r, V), lambda i, tr: (tr[i], 0, 0))],
-        out_specs=pl.BlockSpec((BM, V), lambda i, tr: (i, 0)),
+        grid=(n_slots // block_m,),
+        in_specs=[pl.BlockSpec((S, LANES), lambda i, tr: (i, 0)),
+                  pl.BlockSpec((C8, block_r), lambda i, tr: (0, tr[i]))],
+        out_specs=pl.BlockSpec((n_chan, S, LANES),
+                               lambda i, tr: (0, i, 0)),
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, block_r=block_r),
+    out = pl.pallas_call(
+        functools.partial(_kernel, block_r=block_r, n_chan=n_chan),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Ep, V), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_chan, n_slots // LANES, LANES),
+                                       jnp.float32),
         interpret=interpret,
-    )(tile_row, edge_src[:, None], edge_val[:, None],
-      values.reshape(N // block_r, block_r, V))
+        name="csr_spmv",
+    )(tile_row, slot_src.reshape(-1, LANES), table)
+    return out.reshape(n_chan, n_slots)

@@ -38,56 +38,50 @@ def segment_combine_ref(seg_ids: jax.Array, payload: jax.Array,
     return folded, is_last
 
 
+def fold_lane_dense_ref(key, pay, op: str, *, block_m: int):
+    """jnp re-execution of the Pallas kernel's exact computation
+    (``segment_combine.fold_lane_dense``): the same ``fold_tile`` on the
+    same (R, L) tiles, with the tile carry threaded by ``lax.scan`` and
+    reset per partition. key: (P, M) int32; pay: (P, D, M); M a multiple
+    of ``block_m``. -> folded (P, D, M).
+
+    ``segment_combine_ref`` above is the readable oracle, but its
+    ``associative_scan`` brackets float sums differently, so its low bits
+    can differ from the kernel's. The engine's ``kernel_impl="ref"``
+    sender combine folds through THIS function so that "ref" and "pallas"
+    runs stay bit-for-bit identical even for ``op="sum"``.
+
+    The scan keeps the trace O(1) in the number of tiles (an unrolled
+    loop would make compile time grow with the graph)."""
+    from repro.kernels.segment_combine.segment_combine import (
+        IDENT, NO_KEY, fold_tile, tile_shape)
+    P, D, M = pay.shape
+    R, L = tile_shape(block_m)
+    n = M // block_m
+    kt = key.reshape(P, n, R, L).transpose(1, 0, 2, 3)
+    pt = pay.reshape(P, D, n, R, L).transpose(2, 0, 1, 3, 4)
+
+    def tile(carry, kp):
+        ck, cv = carry
+        k, p = kp
+        out = jax.vmap(lambda k1, p1, ck1, cv1: jnp.stack(fold_tile(
+            k1, tuple(p1), ck1, tuple(cv1), op, jnp.roll)))(k, p, ck, cv)
+        return (k[:, R - 1:R], out[:, :, R - 1:R]), out
+
+    carry0 = (jnp.full((P, 1, L), NO_KEY, jnp.int32),
+              jnp.full((P, D, 1, L), IDENT[op], jnp.float32))
+    _, outs = jax.lax.scan(tile, carry0, (kt, pt))
+    return outs.transpose(1, 2, 0, 3, 4).reshape(P, D, M)
+
+
 def segment_combine_blocked(seg_ids: jax.Array, payload: jax.Array,
                             valid: jax.Array, op: str = "sum", *,
-                            block_m: int = 512):
-    """Plain-jnp re-execution of the Pallas kernel's EXACT computation
-    order: per-tile Hillis-Steele doubling scan + sequential carry splice
-    across tiles (`segment_combine.py:_kernel`).
-
-    `segment_combine_ref` above is the readable oracle, but its
-    `associative_scan` brackets float sums differently, so its low bits
-    can differ from the kernel's. The engine's ``kernel_impl="ref"``
-    sender-combine path folds through THIS function so that "ref" and
-    "pallas" runs stay bit-for-bit identical even for ``op="sum"``
-    (min/max are reduction-order-insensitive either way).
-
-    A ragged final tile is padded with (int32.max, IDENT); the in-tile
-    scan is causal (row i only reads rows < i), so pad rows at the tail
-    cannot perturb real rows.
-
-    The inter-tile carry is a `lax.scan` (NOT a Python loop): the trace
-    stays O(1) in n_tiles, matching the kernel's sequential grid — an
-    unrolled loop makes XLA compile time explode at real graph sizes
-    (webmap-tiny already has ~270 tiles per partition)."""
+                            block_m: int = 1024):
+    """``segment_combine_pallas``'s contract, computed by
+    ``fold_lane_dense_ref``: bit-for-bit the kernel's output."""
     from repro.kernels.segment_combine.segment_combine import (
-        IDENT, _fn, _segmented_scan_tile)
+        is_last_row, lane_dense_inputs)
     M, D = payload.shape
-    BM = min(block_m, M)
-    big = jnp.iinfo(jnp.int32).max
-    seg2 = jnp.where(valid, seg_ids, big)[:, None]
-    pay = jnp.where(valid[:, None], payload, IDENT[op]).astype(jnp.float32)
-    n_tiles = -(-M // BM)
-    pad = n_tiles * BM - M
-    segp = jnp.concatenate([seg2, jnp.full((pad, 1), big, seg2.dtype)])
-    payp = jnp.concatenate([pay, jnp.full((pad, D), IDENT[op], pay.dtype)])
-    fn = _fn(op)
-
-    def tile(carry, sp):
-        prev_seg, prev_val = carry
-        seg, payt = sp
-        v, boundary = _segmented_scan_tile(seg, payt, op)
-        first = jnp.cumsum(boundary.astype(jnp.int32), axis=0) == 1
-        cont = (seg == prev_seg) & first
-        v = jnp.where(cont, fn(prev_val, v), v)
-        return (seg[-1, 0], v[-1:, :]), v
-
-    carry0 = (jnp.int32(-2), jnp.full((1, D), IDENT[op], jnp.float32))
-    _, outs = jax.lax.scan(tile, carry0,
-                           (segp.reshape(n_tiles, BM, 1),
-                            payp.reshape(n_tiles, BM, D)))
-    folded = outs.reshape(n_tiles * BM, D)[:M]
-    s = seg2[:, 0]
-    is_last = jnp.concatenate([s[1:] != s[:-1],
-                               jnp.ones((1,), bool)]) & valid
-    return folded, is_last
+    key, pay = lane_dense_inputs(seg_ids, payload, valid, op, block_m)
+    folded = fold_lane_dense_ref(key, pay, op, block_m=block_m)
+    return folded[0].T[:M], is_last_row(seg_ids, valid)

@@ -19,9 +19,12 @@ from repro.launch import hlo_cost  # noqa: E402
 from repro.launch.mesh import dp_axes, make_production_mesh  # noqa: E402
 from repro.launch.specs import cell_inputs, step_fn_for  # noqa: E402
 
-PEAK_FLOPS = 197e12          # bf16 per chip (TPU v5e)
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+from repro.planner.cost import MACHINES  # noqa: E402
+
+_CHIP = MACHINES["TPU v5 lite"]   # the production mesh's chip
+PEAK_FLOPS = _CHIP.peak_flops
+HBM_BW = _CHIP.hbm_bw
+LINK_BW = _CHIP.link_bw
 
 
 def run_cell(arch: str, shape: str, mesh_kind: str, *,

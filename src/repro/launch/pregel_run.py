@@ -1,36 +1,28 @@
+"""Pregelix job launcher: real runs on the host's devices, sharded runs
+over a device mesh, and the --dryrun lowering for a production mesh.
+
+    PYTHONPATH=src python -m repro.launch.pregel_run --algo sssp \
+        --dataset webmap-tiny --parts 4
+"""
+import argparse
+import json
+import math
 import os
-_argv = __import__("sys").argv
-if "--dryrun" in _argv:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-elif "--devices" in _argv:
-    # sharded real-run: fake that many host devices unless the user set
-    # their own XLA_FLAGS (or runs on real accelerators)
-    try:
-        _n = int(_argv[_argv.index("--devices") + 1])
-    except (ValueError, IndexError):
-        _n = 0
-    if _n > 1 and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={_n}"
+import sys
+import time
+from pathlib import Path
 
-# ^ device count must be set before any jax import.
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-import argparse      # noqa: E402
-import json          # noqa: E402
-import math          # noqa: E402
-import time          # noqa: E402
-from pathlib import Path  # noqa: E402
-
-import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
-
-from repro.core import (N_OVERFLOW, EngineConfig, GlobalState, MsgRel,  # noqa: E402
+from repro.core import (N_OVERFLOW, EngineConfig, GlobalState, MsgRel,
                         PhysicalPlan, VertexRel, make_superstep)
-from repro.graph import SSSP, ConnectedComponents, PageRank  # noqa: E402
-from repro.launch import hlo_cost  # noqa: E402
-from repro.launch.dryrun import HBM_BW, LINK_BW, PEAK_FLOPS  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.graph import SSSP, ConnectedComponents, PageRank
+from repro.launch import hlo_cost
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import make_production_mesh
+from repro.planner.cost import MACHINES
 
 ALGOS = {
     "pagerank": lambda n: PageRank(n, iterations=15),
@@ -117,16 +109,8 @@ def pregel_dryrun(algo: str, scale: str, mesh_kind: str,
     in_specs = (spec_of(vert, axes), spec_of(msg, axes),
                 jax.tree.map(lambda x: P(), gs))
     out_specs = in_specs
-    try:
-        from jax import shard_map
-    except ImportError:   # JAX < 0.6 keeps shard_map in experimental
-        from jax.experimental.shard_map import shard_map
-    try:
-        fn = shard_map(step, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    except TypeError:     # older shard_map spells check_vma check_rep
-        fn = shard_map(step, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
 
     t0 = time.time()
     with mesh:
@@ -134,9 +118,11 @@ def pregel_dryrun(algo: str, scale: str, mesh_kind: str,
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = hlo_cost.analyze(compiled.as_text())
-    terms = {"compute_s": cost.flops / PEAK_FLOPS,
-             "memory_s": cost.bytes / HBM_BW,
-             "collective_s": cost.coll_bytes / LINK_BW}
+    # the production mesh is made of TPU v5e chips
+    chip = MACHINES["TPU v5 lite"]
+    terms = {"compute_s": cost.flops / chip.peak_flops,
+             "memory_s": cost.bytes / chip.hbm_bw,
+             "collective_s": cost.coll_bytes / chip.link_bw}
     return {
         "arch": f"pregelix-{algo}", "shape": scale, "mesh": mesh_kind,
         "status": "ok", "kind": "superstep", "chips": P_total,
@@ -162,7 +148,26 @@ def dataclass_dict(p):
     return dataclasses.asdict(p)
 
 
+def _fake_host_devices(argv) -> None:
+    """Give the CPU backend fake devices for a --dryrun (512) or a
+    sharded --devices N run, unless the user set their own XLA_FLAGS.
+    Must run before JAX initializes its backends."""
+    n = 0
+    if "--dryrun" in argv:
+        n = 512
+    elif "--devices" in argv:
+        try:
+            n = int(argv[argv.index("--devices") + 1])
+        except (ValueError, IndexError):
+            n = 0
+    if n > 1 and "XLA_FLAGS" not in os.environ:
+        os.environ["XLA_FLAGS"] = \
+            f"--xla_force_host_platform_device_count={n}"
+
+
 def main():
+    _fake_host_devices(sys.argv)
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dryrun", action="store_true")
     ap.add_argument("--algo", default="pagerank", choices=list(ALGOS))
@@ -309,6 +314,7 @@ def main():
         out_dir.mkdir(parents=True, exist_ok=True)
         meshes = (["single", "multi"] if args.mesh == "both"
                   else [args.mesh])
+        failed = 0
         for mk in meshes:
             name = f"{args.tag}_pregelix-{args.algo}_{args.scale}_{mk}.json"
             print(f"[pregel-dryrun] {args.algo} x {args.scale} x {mk}",
@@ -326,7 +332,11 @@ def main():
                       f"mem/dev={rec['memory']['total_per_device_bytes']/2**30:.2f}GiB "
                       f"dominant={r['dominant']}", flush=True)
             else:
+                failed += 1
                 print("  error:", rec["error"][:200], flush=True)
+        if failed:
+            raise SystemExit(f"[pregel-dryrun] {failed} of {len(meshes)} "
+                             "lowerings failed")
         return
 
     # small-scale real run (CPU demo)

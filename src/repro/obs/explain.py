@@ -130,7 +130,7 @@ class ExplainLedger:
             return None
         from repro.planner.adaptive import (AdaptiveConfig,
                                             AdaptiveController)
-        from repro.planner.cost import DEFAULT_MACHINE, GraphStats
+        from repro.planner.cost import GraphStats, machine_for
         if g is None:
             if vert is None:
                 return None
@@ -138,7 +138,7 @@ class ExplainLedger:
         self._g = g
         self._auditor = AdaptiveController(
             program, g, plan, config or AdaptiveConfig(),
-            machine=machine or DEFAULT_MACHINE, space_kw=space_kw or {})
+            machine=machine or machine_for(), space_kw=space_kw or {})
         return self
 
     # ---- per-superstep audit row -------------------------------------

@@ -19,7 +19,8 @@ Entry points: ``run_host(..., plan="auto")``, ``run_jit(..., plan="auto")``,
 from repro.planner.adaptive import (AdaptiveConfig, AdaptiveController,
                                     migrate_msgs, resolve_auto_plan)
 from repro.planner.cost import (DEFAULT_MACHINE, EMULATED_MACHINE,
-                                GraphStats, MachineModel, Observation,
+                                MACHINES, GraphStats, MachineModel,
+                                Observation, machine_for,
                                 PlanCost, bucket_cap, calibrate_machine,
                                 estimate, hlo_calibrate,
                                 refit_frontier_cap)
@@ -29,7 +30,7 @@ from repro.planner.stats import StatsCollector, SuperstepStats, msg_bytes
 __all__ = [
     "AdaptiveConfig", "AdaptiveController", "migrate_msgs",
     "resolve_auto_plan", "DEFAULT_MACHINE", "EMULATED_MACHINE",
-    "GraphStats", "MachineModel",
+    "MACHINES", "machine_for", "GraphStats", "MachineModel",
     "Observation", "PlanCost", "bucket_cap", "calibrate_machine",
     "estimate", "hlo_calibrate",
     "refit_frontier_cap", "choose", "plan_space", "rank", "StatsCollector",

@@ -69,12 +69,13 @@ INTERPRET_PENALTY = 8.0
 
 @dataclass(frozen=True)
 class MachineModel:
-    """Roofline constants (defaults: TPU v5e, as in launch/dryrun.py) plus
-    the analytic cost constants, so ``calibrate_machine`` can refit the
+    """Roofline constants of one device (entries of ``MACHINES``) plus the
+    analytic cost constants, so ``calibrate_machine`` can refit the
     latter per backend without touching module globals."""
-    peak_flops: float = 197e12   # bf16 flops/s per chip
-    hbm_bw: float = 819e9        # bytes/s per chip
-    link_bw: float = 50e9        # bytes/s per ICI link
+    peak_flops: float            # bf16 flops/s per chip
+    hbm_bw: float                # bytes/s per chip
+    link_bw: float               # bytes/s per ICI link
+    hbm_bytes: float             # device memory per chip
     host_bw: float = 32e9        # bytes/s device<->host (PCIe-class); the
                                  # OOC streaming traffic and storage
                                  # write-back cross this link
@@ -110,26 +111,45 @@ class MachineModel:
     mxu: bool = True
 
 
-DEFAULT_MACHINE = MachineModel()
-# emulated transport (single host): the "exchange" is a transpose through
-# memory and the "host link" is a memcpy, not an ICI/PCIe hop — the host
-# drivers plan with this model (the delta-vs-inplace distinction survives:
-# scatter amplification vs streaming is a memory-system property). The
-# DISK is a real disk either way, so disk_bw keeps its default; "host
-# memory" is the same memory system as everything else here.
-EMULATED_MACHINE = MachineModel(link_bw=DEFAULT_MACHINE.hbm_bw,
-                                host_bw=DEFAULT_MACHINE.hbm_bw,
-                                host_mem_bw=DEFAULT_MACHINE.hbm_bw,
-                                # fake host devices: the all_to_all is a
-                                # memcpy (memory-class bandwidth) but each
-                                # exchange STAGE pays a real dispatch
-                                # latency (ms-class on the CPU client) —
-                                # this is what keeps the modeled exchange
-                                # within the clamp of the measured-span
-                                # calibration (Observation.net_scale)
-                                net_bw=DEFAULT_MACHINE.hbm_bw,
-                                net_latency_s=1e-3,
-                                mxu=False)
+# One entry per ``jax.Device.device_kind`` the system runs on.
+# "TPU v5 lite" (TPU v5e): Google Cloud TPU documentation, "TPU v5e" —
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect per chip (4 links of 50 GB/s).
+# "cpu": the host emulator. The "exchange" is a transpose through memory
+# and the "host link" is a memcpy, not an ICI/PCIe hop (the
+# delta-vs-inplace distinction survives: scatter amplification vs
+# streaming is a memory-system property). The DISK is a real disk either
+# way, so disk_bw keeps its default. The fake host devices' all_to_all is
+# a memcpy, but each exchange STAGE pays a real dispatch latency
+# (ms-class on the CPU client) — this is what keeps the modeled exchange
+# within the clamp of the measured-span calibration
+# (Observation.net_scale). No Pallas kernel compiles there (mxu=False).
+_V5E = MachineModel(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9,
+                    hbm_bytes=16e9)
+MACHINES = {
+    "TPU v5 lite": _V5E,
+    "cpu": MachineModel(peak_flops=_V5E.peak_flops, hbm_bw=_V5E.hbm_bw,
+                        link_bw=_V5E.hbm_bw, hbm_bytes=_V5E.hbm_bytes,
+                        host_bw=_V5E.hbm_bw, host_mem_bw=_V5E.hbm_bw,
+                        net_bw=_V5E.hbm_bw, net_latency_s=1e-3, mxu=False),
+}
+DEFAULT_MACHINE = MACHINES["TPU v5 lite"]
+EMULATED_MACHINE = MACHINES["cpu"]
+
+
+def machine_for(device_kind: str | None = None) -> MachineModel:
+    """The ``MACHINES`` entry of ``device_kind`` (default: the first
+    device JAX sees). A device without an entry is an error, not a
+    default."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return MACHINES[device_kind]
+    except KeyError:
+        raise ValueError(f"no machine model for device_kind "
+                         f"{device_kind!r}; known: {sorted(MACHINES)}") \
+            from None
 
 
 @dataclass(frozen=True)

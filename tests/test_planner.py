@@ -347,3 +347,17 @@ def test_adaptive_controller_hysteresis():
     switched = ctl.observe(s4)                 # sustained -> switch
     assert switched is not None and switched.join == "left_outer"
     assert ctl.switches and ctl.plan == switched
+
+
+def test_machine_for_reads_the_device_kind_table():
+    """Every driver prices the device JAX runs on from one table keyed by
+    device_kind; a device without an entry is an error, not a default."""
+    import jax
+    from repro.planner import MACHINES, machine_for
+    assert machine_for() is MACHINES[jax.devices()[0].device_kind]
+    v5e = machine_for("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16e9)
+    assert v5e.mxu and not machine_for("cpu").mxu
+    with pytest.raises(ValueError, match="no machine model"):
+        machine_for("TPU v99")

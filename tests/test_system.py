@@ -183,3 +183,39 @@ def test_kcore_matches_peeling_oracle():
     res = run_host(vert, prog, prog.suggested_plan, max_supersteps=60)
     got = gather_values(res.vertex, n)[:, 1] > 0
     assert (got == alive).all()
+
+
+def test_importing_the_cli_leaves_xla_flags_alone(monkeypatch):
+    """The launcher sets the fake-device count in main(), never on import
+    (a --dryrun argv once rewrote XLA_FLAGS for any importer)."""
+    import importlib
+    import os
+    import sys
+    monkeypatch.setattr(sys, "argv", ["pregel_run", "--dryrun"])
+    before = os.environ.get("XLA_FLAGS")
+    import repro.launch.pregel_run as cli
+    importlib.reload(cli)
+    assert os.environ.get("XLA_FLAGS") == before
+
+
+def test_compile_cache_dir_comes_from_outside_or_the_checkout(
+        monkeypatch, tmp_path):
+    import jax
+    from pathlib import Path
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        # set from outside: JAX reads the variable itself, the code names
+        # no other directory
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        # unset: one fixed directory at the root of the checkout
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = Path(compile_cache.__file__).resolve().parents[3]
+        want = str(root / ".jax_cache")
+        assert compile_cache.setup_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert (root / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
