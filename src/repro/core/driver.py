@@ -35,6 +35,20 @@ from repro.obs.metrics import MetricsRegistry
 
 PlanArg = Union[PhysicalPlan, str]   # a PhysicalPlan or the string "auto"
 
+# The in-memory drivers' host legs, each traced as a ``pregel.<leg>``
+# span on the profiler's clock: leg -> ``repro.obs.trace`` category.
+HOST_LEGS = {"dispatch": "dispatch", "wait": "compute",
+             "readback": "collect", "exchange": "exchange",
+             "regrow": "replan", "replan": "replan", "refit": "replan",
+             "checkpoint": "checkpoint", "callback": "dispatch"}
+
+
+def host_leg(leg: str, superstep: int, **args):
+    """The ``trace.annotate`` span of one host leg of a superstep, tagged
+    with the number that superstep's stats record carries."""
+    return trace.annotate("pregel." + leg, HOST_LEGS[leg],
+                          superstep=superstep, **args)
+
 
 def apply_kernel_impl(plan: PlanArg, kernel_impl: Optional[str],
                       auto_space: Optional[dict]):
@@ -309,36 +323,42 @@ def run_host(vert: VertexRel, program: VertexProgram,
         this_recompiled = recompiled
         recompiled = False
         prev = (vert, msg, gs)
-        with trace.annotate("superstep", "compute"):
+        # a recompile (after a regrow, replan or refit) lands in the
+        # next ``pregel.dispatch``
+        n = i + 1
+        with host_leg("dispatch", n):
             vert2, msg2, gs2 = step(vert, msg, gs, None, layout)
+        with host_leg("wait", n):
             jax.block_until_ready(gs2.superstep)
-        ovf_delta = np.asarray(gs2.overflow) - np.asarray(gs.overflow)
+        with host_leg("readback", n):
+            ovf_delta = np.asarray(gs2.overflow) - np.asarray(gs.overflow)
         if (ovf_delta > 0).any():
             # grow ONLY the overflowed capacities x2 and REDO this
             # superstep from `prev` (per-source counters keep a frontier
             # overflow from dragging the bucket tensors along)
-            ec = grow_overflowed(ec, ovf_delta,
-                                 vertex_capacity=vert.capacity)
-            step = jax.jit(make_superstep(program, plan, ec))
-            vert, msg, gs = prev
-            msg = _regrow_msgs(msg, ec)
+            with host_leg("regrow", n):
+                ec = grow_overflowed(ec, ovf_delta,
+                                     vertex_capacity=vert.capacity)
+                step = jax.jit(make_superstep(program, plan, ec))
+                vert, msg, gs = prev
+                msg = _regrow_msgs(msg, ec)
             stats.append(coll.event(
                 i, "regrow", bucket_cap=ec.bucket_cap,
                 frontier_cap=ec.frontier_cap,
                 mutation_cap=ec.mutation_cap,
                 sources=np.flatnonzero(ovf_delta > 0).tolist()).as_dict())
             m_regrows.inc()
-            trace.instant("regrow", "replan", superstep=i)
             recompiled = True
             if controller is not None:
                 controller.note_shape_change()
             continue
         vert, msg, gs = vert2, msg2, gs2
         i += 1
-        rec = coll.record(i, active=int(gs.active_count),
-                          messages=int(gs.msg_count),
-                          wall_s=time.time() - ts,
-                          recompiled=this_recompiled)
+        with host_leg("readback", n):
+            rec = coll.record(i, active=int(gs.active_count),
+                              messages=int(gs.msg_count),
+                              wall_s=time.time() - ts,
+                              recompiled=this_recompiled)
         stats.append(rec.as_dict())
         if explain.enabled():
             # audit the plan that EXECUTED this superstep (a switch
@@ -354,39 +374,39 @@ def run_host(vert: VertexRel, program: VertexProgram,
         if controller is not None and not bool(gs.halt):
             # mid-run replanning: switch the physical plan when observed
             # frontier density pushes another plan below the current one
-            with trace.span("replan", "replan"):
+            with host_leg("replan", n):
                 new_plan = controller.observe(rec,
                                               bucket_cap=ec.bucket_cap)
-            if new_plan is not None:
-                from repro.planner import migrate_msgs
-                msg = migrate_msgs(msg, plan, new_plan, ec.n_parts)
-                plan = new_plan
-                if plan.join == "left_outer":
-                    act = int(gs.active_count) // \
-                        max(vert.num_partitions, 1) + 1
-                    ec = dataclasses.replace(
-                        ec, frontier_cap=min(max(FRONTIER_FLOOR, act * 4),
-                                             vert.capacity + 8))
-                # dropping the sender combine needs room for uncombined
-                # sends: grow the buckets now instead of paying an
-                # overflow-redo on the next superstep
-                need = default_engine_config(vert, program, plan)
-                if need.bucket_cap > ec.bucket_cap:
-                    ec = dataclasses.replace(ec,
-                                             bucket_cap=need.bucket_cap)
-                    msg = _regrow_msgs(msg, ec)
-                step = jax.jit(make_superstep(program, plan, ec))
-                layout = plan_gather_layout(plan, vert)
-                stats.append(coll.event(
-                    i, "plan-switch", join=plan.join,
-                    groupby=plan.groupby, connector=plan.connector,
-                    sender_combine=plan.sender_combine,
-                    storage=plan.storage,
-                    frontier_cap=ec.frontier_cap).as_dict())
-                m_switches.inc()
-                recompiled = True
-                switched = True
-                controller.note_shape_change()
+                if new_plan is not None:
+                    from repro.planner import migrate_msgs
+                    msg = migrate_msgs(msg, plan, new_plan, ec.n_parts)
+                    plan = new_plan
+                    if plan.join == "left_outer":
+                        act = int(gs.active_count) // \
+                            max(vert.num_partitions, 1) + 1
+                        ec = dataclasses.replace(
+                            ec, frontier_cap=min(max(FRONTIER_FLOOR, act * 4),
+                                                 vert.capacity + 8))
+                    # dropping the sender combine needs room for uncombined
+                    # sends: grow the buckets now instead of paying an
+                    # overflow-redo on the next superstep
+                    need = default_engine_config(vert, program, plan)
+                    if need.bucket_cap > ec.bucket_cap:
+                        ec = dataclasses.replace(ec,
+                                                 bucket_cap=need.bucket_cap)
+                        msg = _regrow_msgs(msg, ec)
+                    step = jax.jit(make_superstep(program, plan, ec))
+                    layout = plan_gather_layout(plan, vert)
+                    stats.append(coll.event(
+                        i, "plan-switch", join=plan.join,
+                        groupby=plan.groupby, connector=plan.connector,
+                        sender_combine=plan.sender_combine,
+                        storage=plan.storage,
+                        frontier_cap=ec.frontier_cap).as_dict())
+                    m_switches.inc()
+                    recompiled = True
+                    switched = True
+                    controller.note_shape_change()
         # adaptive frontier refit (left-outer plan): when the live set
         # collapses, shrink the frontier capacity so each superstep only
         # pays O(|frontier|) — one recompile, amortized across supersteps
@@ -394,9 +414,10 @@ def run_host(vert: VertexRel, program: VertexProgram,
             act = int(gs.active_count) // max(vert.num_partitions, 1) + 1
             if act * 4 < ec.frontier_cap and ec.frontier_cap > \
                     FRONTIER_FLOOR:
-                ec = dataclasses.replace(
-                    ec, frontier_cap=max(FRONTIER_FLOOR, act * 2))
-                step = jax.jit(make_superstep(program, plan, ec))
+                with host_leg("refit", n):
+                    ec = dataclasses.replace(
+                        ec, frontier_cap=max(FRONTIER_FLOOR, act * 2))
+                    step = jax.jit(make_superstep(program, plan, ec))
                 stats.append(coll.event(
                     i, "frontier-refit",
                     frontier_cap=ec.frontier_cap).as_dict())
@@ -415,11 +436,14 @@ def run_host(vert: VertexRel, program: VertexProgram,
             failure_injector(i, vert, msg, gs)
         if checkpoint_every and i % checkpoint_every == 0 \
                 and checkpoint_dir:
-            with trace.span("checkpoint", "checkpoint"):
+            with host_leg("checkpoint", n):
                 save_checkpoint(checkpoint_dir, i, vert, msg, gs)
         if on_superstep is not None:
-            on_superstep(i, vert, msg, gs, rec.as_dict())
-        if bool(gs.halt):
+            with host_leg("callback", n):
+                on_superstep(i, vert, msg, gs, rec.as_dict())
+        with host_leg("readback", n):
+            halted = bool(gs.halt)
+        if halted:
             break
     return RunResult(vertex=vert, gs=gs, supersteps=i, stats=stats,
                      wall_s=time.time() - t0, plan=plan)

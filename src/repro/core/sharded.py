@@ -57,12 +57,12 @@ from repro.core import connector
 from repro.core.driver import (PlanArg, RunResult, _regrow_msgs,
                                _resolve_plan, apply_kernel_impl,
                                default_engine_config, grow_overflowed,
-                               init_vertex_values)
+                               host_leg, init_vertex_values)
 from repro.core.plan import FRONTIER_FLOOR, PhysicalPlan
 from repro.core.program import VertexProgram
 from repro.core.relations import (GlobalState, MsgRel, VertexRel,
                                   empty_msgs, init_gs)
-from repro.core.superstep import EngineConfig, make_superstep
+from repro.core.superstep import ROUTE, EngineConfig, make_superstep
 from repro.obs import explain, memwatch, trace
 from repro.obs.metrics import MetricsRegistry
 
@@ -284,7 +284,11 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
         the pre-exchange buckets as new_msg) + the separately-timed
         all_to_all exchange stage."""
         fn = make_superstep(program, plan, ec)
-        body = lambda v, m, g: fn(v, m, g, None, None)
+
+        # named, so the profiler names the programs ``jit_superstep`` and
+        # ``jit_exchange``, as the trace readers look for them
+        def superstep(v, m, g):
+            return fn(v, m, g, None, None)
 
         # out_specs are written by hand: the body contains psums over the
         # mesh axes, so eval_shape outside shard_map would fail on the
@@ -299,9 +303,10 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
                            valid=PSpec(axes, None, None))
         in_specs = (v_specs, m_specs, g_specs)
         out_specs = (v_specs, bkt_specs, g_specs)
-        step = jax.jit(_shard_map(body, mesh, in_specs, out_specs))
+        step = jax.jit(_shard_map(superstep, mesh, in_specs, out_specs))
 
-        def ex_body(m: MsgRel) -> MsgRel:
+        @jax.named_scope(ROUTE)
+        def exchange(m: MsgRel) -> MsgRel:
             r_dst, r_pay, r_val = connector.exchange_shard_map(
                 m.dst, m.payload, m.valid, axes)
             P_l = m.dst.shape[0]
@@ -309,7 +314,7 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
             return MsgRel(dst=flat(r_dst), payload=flat(r_pay),
                           valid=flat(r_val))
 
-        ex = jax.jit(_shard_map(ex_body, mesh, (bkt_specs,), m_specs))
+        ex = jax.jit(_shard_map(exchange, mesh, (bkt_specs,), m_specs))
         return step, ex
 
     step, exchange = build_step(plan, ec)
@@ -345,49 +350,51 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
         this_recompiled = recompiled
         recompiled = False
         prev = (vert, msg, gs)
-        with trace.annotate("superstep", "compute"):
+        n = i + 1
+        with host_leg("dispatch", n):
             vert2, buckets, gs2 = step(vert, msg, gs)
+        with host_leg("wait", n):
             jax.block_until_ready(gs2.superstep)
-        ovf_delta = np.asarray(gs2.overflow) - np.asarray(gs.overflow)
+        with host_leg("readback", n):
+            ovf_delta = np.asarray(gs2.overflow) - np.asarray(gs.overflow)
         if (ovf_delta > 0).any():
-            ec = grow_overflowed(ec, ovf_delta,
-                                 vertex_capacity=vert.capacity)
-            step, exchange = build_step(plan, ec)
-            vert, msg, gs = prev
-            msg = put_lead(_regrow_msgs(msg, ec))
+            with host_leg("regrow", n):
+                ec = grow_overflowed(ec, ovf_delta,
+                                     vertex_capacity=vert.capacity)
+                step, exchange = build_step(plan, ec)
+                vert, msg, gs = prev
+                msg = put_lead(_regrow_msgs(msg, ec))
             stats.append(coll.event(
                 i, "regrow", bucket_cap=ec.bucket_cap,
                 frontier_cap=ec.frontier_cap,
                 mutation_cap=ec.mutation_cap,
                 sources=np.flatnonzero(ovf_delta > 0).tolist()).as_dict())
             m_regrows.inc()
-            trace.instant("regrow", "replan", superstep=i)
             recompiled = True
             if controller is not None:
                 controller.note_shape_change()
             continue
         # ---- the all_to_all exchange, as its own timed stage ----------
         faults.hit("sharded.exchange", f"s{i}")
-        t_ex = time.time()
-        msg = exchange(buckets)
-        jax.block_until_ready(msg.valid)
-        t_done = time.time()
-        ex_stall = t_done - t_ex
         ex_bytes = _exchange_wire_bytes(P, ec.n_parts, ec.bucket_cap,
                                         program.msg_dims, N)
-        trace.complete("exchange", "exchange", t_ex, t_done,
-                       superstep=i + 1, bytes=ex_bytes, workers=N)
+        with host_leg("exchange", n, bytes=ex_bytes, workers=N):
+            t_ex = time.time()
+            msg = exchange(buckets)
+            jax.block_until_ready(msg.valid)
+            ex_stall = time.time() - t_ex
         m_exb.inc(ex_bytes)
         m_exs.inc(ex_stall)
         vert, gs = vert2, gs2
         i += 1
-        rec = coll.record(i, active=int(gs.active_count),
-                          messages=int(gs.msg_count),
-                          wall_s=time.time() - ts,
-                          recompiled=this_recompiled,
-                          sharded=True, n_workers=N,
-                          exchange_bytes=ex_bytes,
-                          exchange_stall_s=ex_stall)
+        with host_leg("readback", n):
+            rec = coll.record(i, active=int(gs.active_count),
+                              messages=int(gs.msg_count),
+                              wall_s=time.time() - ts,
+                              recompiled=this_recompiled,
+                              sharded=True, n_workers=N,
+                              exchange_bytes=ex_bytes,
+                              exchange_stall_s=ex_stall)
         stats.append(rec.as_dict())
         if explain.enabled():
             explain.superstep(rec, plan=plan, bucket_cap=ec.bucket_cap)
@@ -395,41 +402,42 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
             memwatch.sample(i)
         switched = False
         if controller is not None and not bool(gs.halt):
-            with trace.span("replan", "replan"):
+            with host_leg("replan", n):
                 new_plan = controller.observe(rec, bucket_cap=ec.bucket_cap)
-            if new_plan is not None:
-                from repro.planner import migrate_msgs
-                msg = put_lead(migrate_msgs(msg, plan, new_plan,
-                                            ec.n_parts))
-                plan = new_plan
-                if plan.join == "left_outer":
-                    act = int(gs.active_count) // max(P, 1) + 1
-                    ec = dataclasses.replace(
-                        ec, frontier_cap=min(max(FRONTIER_FLOOR, act * 4),
-                                             vert.capacity + 8))
-                need = default_engine_config(vert, program, plan)
-                if need.bucket_cap > ec.bucket_cap:
-                    ec = dataclasses.replace(ec,
-                                             bucket_cap=need.bucket_cap)
-                    msg = put_lead(_regrow_msgs(msg, ec))
-                step, exchange = build_step(plan, ec)
-                stats.append(coll.event(
-                    i, "plan-switch", join=plan.join,
-                    groupby=plan.groupby, connector=plan.connector,
-                    sender_combine=plan.sender_combine,
-                    storage=plan.storage,
-                    frontier_cap=ec.frontier_cap).as_dict())
-                m_switches.inc()
-                recompiled = True
-                switched = True
-                controller.note_shape_change()
+                if new_plan is not None:
+                    from repro.planner import migrate_msgs
+                    msg = put_lead(migrate_msgs(msg, plan, new_plan,
+                                                ec.n_parts))
+                    plan = new_plan
+                    if plan.join == "left_outer":
+                        act = int(gs.active_count) // max(P, 1) + 1
+                        ec = dataclasses.replace(
+                            ec, frontier_cap=min(max(FRONTIER_FLOOR, act * 4),
+                                                 vert.capacity + 8))
+                    need = default_engine_config(vert, program, plan)
+                    if need.bucket_cap > ec.bucket_cap:
+                        ec = dataclasses.replace(ec,
+                                                 bucket_cap=need.bucket_cap)
+                        msg = put_lead(_regrow_msgs(msg, ec))
+                    step, exchange = build_step(plan, ec)
+                    stats.append(coll.event(
+                        i, "plan-switch", join=plan.join,
+                        groupby=plan.groupby, connector=plan.connector,
+                        sender_combine=plan.sender_combine,
+                        storage=plan.storage,
+                        frontier_cap=ec.frontier_cap).as_dict())
+                    m_switches.inc()
+                    recompiled = True
+                    switched = True
+                    controller.note_shape_change()
         if plan.join == "left_outer" and not switched:
             act = int(gs.active_count) // max(P, 1) + 1
             if act * 4 < ec.frontier_cap and \
                     ec.frontier_cap > FRONTIER_FLOOR:
-                ec = dataclasses.replace(
-                    ec, frontier_cap=max(FRONTIER_FLOOR, act * 2))
-                step, exchange = build_step(plan, ec)
+                with host_leg("refit", n):
+                    ec = dataclasses.replace(
+                        ec, frontier_cap=max(FRONTIER_FLOOR, act * 2))
+                    step, exchange = build_step(plan, ec)
                 stats.append(coll.event(
                     i, "frontier-refit",
                     frontier_cap=ec.frontier_cap).as_dict())
@@ -438,11 +446,14 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
                     controller.note_shape_change()
         if checkpoint_every and i % checkpoint_every == 0 \
                 and checkpoint_dir:
-            with trace.span("checkpoint", "checkpoint"):
+            with host_leg("checkpoint", n):
                 save_checkpoint(checkpoint_dir, i, vert, msg, gs)
         if on_superstep is not None:
-            on_superstep(i, rec.as_dict())
-        if bool(gs.halt):
+            with host_leg("callback", n):
+                on_superstep(i, rec.as_dict())
+        with host_leg("readback", n):
+            halted = bool(gs.halt)
+        if halted:
             break
     return RunResult(vertex=vert, gs=gs, supersteps=i, stats=stats,
                      wall_s=time.time() - t0, plan=plan)
@@ -547,6 +558,7 @@ def _run_sharded_ooc(vert, program, plan, *, mesh, axes, n_workers,
         partitions with an inbox of run width C_in, plus the raw
         (worker-major) all_to_all for its collected buckets."""
         fn = make_superstep(program, plan, ec)
+
         body = lambda v, m, g: fn(v, m, g, None, None)
         # hand-written specs (psums in the body rule out eval_shape
         # outside shard_map); the inbox run width C_in only affects

@@ -27,6 +27,16 @@ from repro.core.program import ComputeOut, VertexProgram
 from repro.core.relations import GlobalState, MsgRel, VertexRel
 from repro.kernels import backend as kbackend
 
+# The superstep's stages, each traced under its ``jax.named_scope``: the
+# name lands in every HLO instruction's ``op_name``, so a profiler trace
+# can time a stage whatever implements it. A fusion takes the scope of
+# its root instruction.
+STAGES = ("pregel.receive", "pregel.compute", "pregel.edge_gate",
+          "pregel.gather", "pregel.sender_combine", "pregel.route",
+          "pregel.mutate", "pregel.global")
+RECEIVE, COMPUTE, EDGE_GATE, GATHER, SENDER_COMBINE, ROUTE, MUTATE, \
+    GLOBAL = STAGES
+
 
 @dataclass(frozen=True)
 class EngineConfig:
@@ -231,39 +241,44 @@ def make_superstep(program: VertexProgram, plan: PhysicalPlan,
         comes from."""
         P, Np = vert.vid.shape
         Ep = vert.edge_src.shape[1]
-        esl = vert.edge_src.clip(0)
-        egate = jnp.take_along_axis(gate_dense, esl, axis=1) & \
-            (vert.edge_src >= 0) & (vert.edge_dst >= 0)
-        edge_src, edge_dst, edge_val = (vert.edge_src, vert.edge_dst,
-                                        vert.edge_val)
-        if plan.join == "left_outer":
-            EF = min(max(ec.frontier_cap * 8, 64), Ep)
-            eidx, _, ovf_e = jax.vmap(
-                lambda m: groupby.compact(m, EF))(egate)
-            take1 = lambda a: jnp.take_along_axis(a, eidx.clip(0), axis=1)
-            edge_src = jnp.where(eidx >= 0, take1(vert.edge_src), -1)
-            edge_dst = jnp.where(eidx >= 0, take1(vert.edge_dst), -1)
-            edge_val = take1(vert.edge_val)
-            egate = eidx >= 0
-            esl = edge_src.clip(0)
-            ovf_edges = jnp.sum(ovf_e)
-        else:
-            ovf_edges = jnp.zeros((), jnp.int32)
-        src_vid = jnp.take_along_axis(vert.vid, esl, axis=1)
-        if kernel_gather and layout is not None:
-            # row-blocked csr_spmv Pallas kernel: the gather becomes
-            # one-hot MXU matmuls over the host-planned tiling. Invalid
-            # lanes read 0.0 where the jnp path reads row 0 — both are
-            # masked by egate before anything observable.
-            src_val = kbackend.edge_gather_values(
-                value_new, edge_src, layout, impl_r=impl_r)
-        else:
-            # one (P, Ep) gather per value channel: a (P, Ep, V) gather
-            # index would pad its narrow minor dimension to a full tile
-            src_val = jnp.stack(
-                [jnp.take_along_axis(value_new[..., c], esl, axis=1)
-                 for c in range(value_new.shape[-1])], axis=-1)
-        payload = program.send(src_vid, src_val, edge_val, edge_dst, gs)
+        with jax.named_scope(EDGE_GATE):
+            esl = vert.edge_src.clip(0)
+            egate = jnp.take_along_axis(gate_dense, esl, axis=1) & \
+                (vert.edge_src >= 0) & (vert.edge_dst >= 0)
+            edge_src, edge_dst, edge_val = (vert.edge_src, vert.edge_dst,
+                                            vert.edge_val)
+            if plan.join == "left_outer":
+                EF = min(max(ec.frontier_cap * 8, 64), Ep)
+                eidx, _, ovf_e = jax.vmap(
+                    lambda m: groupby.compact(m, EF))(egate)
+                take1 = lambda a: jnp.take_along_axis(a, eidx.clip(0),
+                                                      axis=1)
+                edge_src = jnp.where(eidx >= 0, take1(vert.edge_src), -1)
+                edge_dst = jnp.where(eidx >= 0, take1(vert.edge_dst), -1)
+                edge_val = take1(vert.edge_val)
+                egate = eidx >= 0
+                esl = edge_src.clip(0)
+                ovf_edges = jnp.sum(ovf_e)
+            else:
+                ovf_edges = jnp.zeros((), jnp.int32)
+        with jax.named_scope(GATHER):
+            src_vid = jnp.take_along_axis(vert.vid, esl, axis=1)
+            if kernel_gather and layout is not None:
+                # row-blocked csr_spmv Pallas kernel: the gather becomes
+                # one-hot MXU matmuls over the host-planned tiling.
+                # Invalid lanes read 0.0 where the jnp path reads row 0 —
+                # both are masked by egate before anything observable.
+                src_val = kbackend.edge_gather_values(
+                    value_new, edge_src, layout, impl_r=impl_r)
+            else:
+                # one (P, Ep) gather per value channel: a (P, Ep, V)
+                # gather index would pad its narrow minor dimension to a
+                # full tile
+                src_val = jnp.stack(
+                    [jnp.take_along_axis(value_new[..., c], esl, axis=1)
+                     for c in range(value_new.shape[-1])], axis=-1)
+            payload = program.send(src_vid, src_val, edge_val, edge_dst,
+                                   gs)
         return edge_dst, payload, egate, ovf_edges
 
     def sender_combine(dst, payload, valid):
@@ -374,29 +389,33 @@ def make_superstep(program: VertexProgram, plan: PhysicalPlan,
         traced — no re-tracing across super-partitions."""
         P, Np = vert.vid.shape
         # 1-2. receiver group-by + join + select (D1)
-        combined, has_msg = receiver_groupby(msg, Np)
-        if getattr(program, "mutates", False):
-            vert = resurrect(vert, has_msg, part0)
-        out, active, frontier = run_compute(vert, combined, has_msg, gs)
-        # 3. vertex updates (D2)
-        value, halt, gate, agg = apply_updates(vert, out, active, frontier)
+        with jax.named_scope(RECEIVE):
+            combined, has_msg = receiver_groupby(msg, Np)
+            if getattr(program, "mutates", False):
+                vert = resurrect(vert, has_msg, part0)
+        with jax.named_scope(COMPUTE):
+            out, active, frontier = run_compute(vert, combined, has_msg, gs)
+            # 3. vertex updates (D2)
+            value, halt, gate, agg = apply_updates(vert, out, active,
+                                                   frontier)
         # 4. message generation + sender combine + exchange (D3/D7)
         dst, payload, valid, ovf_edges = gen_messages(vert, value, gate, gs,
                                                       layout)
         presorted = False
         ovf_pack = jnp.zeros((), jnp.int32)
         if plan.sender_combine:
-            dst, payload, valid = sender_combine(dst, payload, valid)
-            presorted = True  # sort_combine leaves dst ascending
-            capc = n_parts * ec.bucket_cap
-            if fuse_pack and capc < dst.shape[1]:
-                dst, payload, valid, ovf_pack = compact_combined(
-                    dst, payload, valid, capc)
+            with jax.named_scope(SENDER_COMBINE):
+                dst, payload, valid = sender_combine(dst, payload, valid)
+                presorted = True  # sort_combine leaves dst ascending
+                capc = n_parts * ec.bucket_cap
+                if fuse_pack and capc < dst.shape[1]:
+                    dst, payload, valid, ovf_pack = compact_combined(
+                        dst, payload, valid, capc)
         collect_msgs = ec.ooc_collect or ec.exchange_apart
-        r_dst, r_pay, r_val, ovf = route(dst, payload, valid, ec.bucket_cap,
-                                         Np, collect=collect_msgs,
-                                         presorted=presorted)
-        ovf_f = frontier[2].sum() if frontier is not None else 0
+        with jax.named_scope(ROUTE):
+            r_dst, r_pay, r_val, ovf = route(
+                dst, payload, valid, ec.bucket_cap, Np,
+                collect=collect_msgs, presorted=presorted)
         # 5. mutations (D6)
         m_ovf = jnp.zeros((), jnp.int32)
         mut_buckets = None
@@ -404,58 +423,61 @@ def make_superstep(program: VertexProgram, plan: PhysicalPlan,
         if (out.insert_vid is not None or out.delete_self is not None
                 or out.new_edge_dst is not None
                 or out.new_edge_val is not None):
-            (vid, value, halt, edge_dst, edge_val, m_ovf,
-             mut_buckets) = apply_mutations(vert, value, halt, out, gs)
-        # 6. global state (D4/D5/D8/D9). Overflow is counted PER SOURCE
-        # (bucket / frontier / mutation / edge) so the drivers' regrow
-        # paths double only the capacity that actually overflowed.
-        msg_count = red_sum(r_val).astype(jnp.int32)
-        # (order = relations.OVF_BUCKET/FRONTIER/MUTATION/EDGE)
-        zero = jnp.zeros((), jnp.int32)
-        overflow = jnp.stack([
-            red_sum(ovf).astype(jnp.int32) +
-            red_sum(ovf_pack).astype(jnp.int32),
-            (red_sum(ovf_f).astype(jnp.int32) if frontier is not None
-             else zero),
-            red_sum(m_ovf).astype(jnp.int32),
-            red_sum(ovf_edges).astype(jnp.int32)])
-        active_count = red_sum(active).astype(jnp.int32)
-        if agg is not None:
-            contrib, mask = agg
-            local = jnp.where(mask[..., None], contrib, 0.0) \
-                .reshape(-1, program.agg_dims).sum(0)
-            agg_val = (jax.lax.psum(local, ec.axis_name)
-                       if ec.axis_name is not None else local)
-        else:
-            agg_val = gs.aggregate
-        halt_all = red_all(halt | (vid < 0))
-        g_halt = halt_all & (msg_count == 0)
-        new_vert = VertexRel(vid=vid, halt=halt, value=value,
-                             edge_src=vert.edge_src, edge_dst=edge_dst,
-                             edge_val=edge_val)
-        # under ooc_collect / exchange_apart new_msg carries the
-        # PRE-EXCHANGE (P_local, n_parts, C) buckets — same pytree, one
-        # extra axis; the driver runs the exchange itself
-        new_msg = MsgRel(dst=r_dst, payload=r_pay, valid=r_val)
-        new_gs = GlobalState(
-            halt=g_halt | program.is_converged(gs),
-            aggregate=jnp.asarray(agg_val, jnp.float32).reshape(
-                gs.aggregate.shape),
-            superstep=gs.superstep + 1,
-            overflow=gs.overflow + overflow,
-            active_count=active_count,
-            msg_count=msg_count)
-        if ec.ooc_collect:
-            # extra outputs for the OOC collector: per-(src, dst) bucket
-            # occupancy counts (computed on-device so the host never has
-            # to scan the bucket tensors for the inbox run-width trim /
-            # readiness bookkeeping of the barrier-free pipeline), and
-            # the collected insert-proposal buckets (sp, P, Cm) for the
-            # host mutation inbox — None when the program never proposes
-            # inserts (the pytree stays static per program)
-            counts = jnp.sum(r_val, axis=2, dtype=jnp.int32)
-            return new_vert, new_msg, new_gs, counts, mut_buckets
-        return new_vert, new_msg, new_gs
+            with jax.named_scope(MUTATE):
+                (vid, value, halt, edge_dst, edge_val, m_ovf,
+                 mut_buckets) = apply_mutations(vert, value, halt, out, gs)
+        with jax.named_scope(GLOBAL):
+            ovf_f = frontier[2].sum() if frontier is not None else 0
+            # 6. global state (D4/D5/D8/D9). Overflow is counted PER SOURCE
+            # (bucket / frontier / mutation / edge) so the drivers' regrow
+            # paths double only the capacity that actually overflowed.
+            msg_count = red_sum(r_val).astype(jnp.int32)
+            # (order = relations.OVF_BUCKET/FRONTIER/MUTATION/EDGE)
+            zero = jnp.zeros((), jnp.int32)
+            overflow = jnp.stack([
+                red_sum(ovf).astype(jnp.int32) +
+                red_sum(ovf_pack).astype(jnp.int32),
+                (red_sum(ovf_f).astype(jnp.int32) if frontier is not None
+                 else zero),
+                red_sum(m_ovf).astype(jnp.int32),
+                red_sum(ovf_edges).astype(jnp.int32)])
+            active_count = red_sum(active).astype(jnp.int32)
+            if agg is not None:
+                contrib, mask = agg
+                local = jnp.where(mask[..., None], contrib, 0.0) \
+                    .reshape(-1, program.agg_dims).sum(0)
+                agg_val = (jax.lax.psum(local, ec.axis_name)
+                           if ec.axis_name is not None else local)
+            else:
+                agg_val = gs.aggregate
+            halt_all = red_all(halt | (vid < 0))
+            g_halt = halt_all & (msg_count == 0)
+            new_vert = VertexRel(vid=vid, halt=halt, value=value,
+                                 edge_src=vert.edge_src, edge_dst=edge_dst,
+                                 edge_val=edge_val)
+            # under ooc_collect / exchange_apart new_msg carries the
+            # PRE-EXCHANGE (P_local, n_parts, C) buckets — same pytree, one
+            # extra axis; the driver runs the exchange itself
+            new_msg = MsgRel(dst=r_dst, payload=r_pay, valid=r_val)
+            new_gs = GlobalState(
+                halt=g_halt | program.is_converged(gs),
+                aggregate=jnp.asarray(agg_val, jnp.float32).reshape(
+                    gs.aggregate.shape),
+                superstep=gs.superstep + 1,
+                overflow=gs.overflow + overflow,
+                active_count=active_count,
+                msg_count=msg_count)
+            if ec.ooc_collect:
+                # extra outputs for the OOC collector: per-(src, dst) bucket
+                # occupancy counts (computed on-device so the host never has
+                # to scan the bucket tensors for the inbox run-width trim /
+                # readiness bookkeeping of the barrier-free pipeline), and
+                # the collected insert-proposal buckets (sp, P, Cm) for the
+                # host mutation inbox — None when the program never proposes
+                # inserts (the pytree stays static per program)
+                counts = jnp.sum(r_val, axis=2, dtype=jnp.int32)
+                return new_vert, new_msg, new_gs, counts, mut_buckets
+            return new_vert, new_msg, new_gs
 
     return superstep
 
@@ -473,11 +495,11 @@ def jit_superstep(program: VertexProgram, plan: PhysicalPlan,
     inbox-slice shapes.
 
     The returned callable participates in ``repro.obs`` tracing: each
-    invocation is a ``compute``-category span (and, when the tracer was
-    started with jax_annotations, a ``jax.profiler.TraceAnnotation`` —
-    the bridge that lines host spans up with device activity under the
-    JAX profiler). With tracing off the wrapper is one extra Python call
-    around the jitted function."""
+    invocation is a ``compute``-category span (and, while a JAX profiler
+    session records, a ``jax.profiler.TraceAnnotation`` — the bridge that
+    lines host spans up with device activity under the JAX profiler).
+    With tracing off the wrapper is one extra Python call around the
+    jitted function."""
     from repro.obs import trace
 
     fn = make_superstep(program, plan, ec)

@@ -22,10 +22,10 @@ Design constraints:
   a buffer (registered once under a lock on first use); appends are
   plain ``list.append``. Export snapshots the buffers concurrently with
   recording (``Tracer.drain``).
-* **Device bridging is optional.** ``start(jax_annotations=True)`` makes
-  ``annotate`` also enter a ``jax.profiler.TraceAnnotation``, so spans
-  line up with device activity when the run is profiled with the JAX
-  profiler.
+* **Device bridging.** While a JAX profiler session records,
+  ``annotate`` also enters a ``jax.profiler.TraceAnnotation`` (its kwargs
+  become the event's stats), with or without a tracer, so spans line up
+  with device activity in the profiler's trace. Otherwise it is ``span``.
 
 Span categories (one per pipeline leg; ``CATEGORIES``): ``dispatch``,
 ``prepare``, ``compute``, ``collect``, ``commit``, ``fault``,
@@ -113,18 +113,11 @@ class _Annotated:
 class Tracer:
     """Per-thread span buffers + the clock origin for one recording."""
 
-    def __init__(self, *, jax_annotations: bool = False):
+    def __init__(self):
         self._mu = threading.Lock()
         self._bufs: list = []            # [(tid, thread_name, events)]
         self._local = threading.local()
         self.t_origin = time.time()
-        self.jax_annotation = None
-        if jax_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-                self.jax_annotation = TraceAnnotation
-            except Exception:            # noqa: BLE001 — stays host-only
-                self.jax_annotation = None
 
     def _buf(self) -> list:
         b = getattr(self._local, "buf", None)
@@ -168,10 +161,10 @@ class Tracer:
 _tracer: Optional[Tracer] = None
 
 
-def start(*, jax_annotations: bool = False) -> Tracer:
+def start() -> Tracer:
     """Enable tracing globally; returns the (fresh) tracer."""
     global _tracer
-    _tracer = Tracer(jax_annotations=jax_annotations)
+    _tracer = Tracer()
     return _tracer
 
 
@@ -200,17 +193,30 @@ def span(name: str, cat: str, **args):
     return t.span(name, cat, args or None)
 
 
+def _load_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    importing this module does not import JAX."""
+    global _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+    _TraceAnnotation = TraceAnnotation
+    return TraceAnnotation
+
+
+_TraceAnnotation = None
+
+
 def annotate(name: str, cat: str = "compute", **args):
-    """Like ``span`` but also enters ``jax.profiler.TraceAnnotation``
-    when the tracer was started with ``jax_annotations=True`` — bridges
-    the host-side timeline to device activity under the JAX profiler."""
+    """Like ``span`` but also enters ``jax.profiler.TraceAnnotation``,
+    with ``args`` as the event's stats, while a JAX profiler session
+    records: bridges the host-side timeline to device activity under the
+    JAX profiler. With neither a tracer nor a session, the cached no-op."""
+    ann = _TraceAnnotation or _load_annotation()
     t = _tracer
+    if not ann.is_enabled():
+        return _NULL if t is None else t.span(name, cat, args or None)
     if t is None:
-        return _NULL
-    s = t.span(name, cat, args or None)
-    if t.jax_annotation is not None:
-        return _Annotated(s, t.jax_annotation(name))
-    return s
+        return ann(name, **args)
+    return _Annotated(t.span(name, cat, args or None), ann(name, **args))
 
 
 def complete(name: str, cat: str, t0: float, t1: float, **args):

@@ -14,6 +14,7 @@ import dataclasses
 import json
 import threading
 
+import jax
 import pytest
 
 from repro.core import PhysicalPlan, load_graph
@@ -58,6 +59,32 @@ def test_disabled_tracing_allocates_nothing():
     assert trace.instant("y", "replan") is None
     assert trace.counter("z", 3) is None
     assert trace.get() is None
+
+
+def test_disabled_annotate_with_kwargs_is_the_cached_null():
+    """``annotate`` with span args, no tracer and no profiler session
+    recording still returns the cached no-op singleton."""
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    s = trace.annotate("pregel.dispatch", "dispatch", superstep=3)
+    assert s is trace.annotate("pregel.wait", "compute", superstep=4)
+    assert s is trace.span("a", "compute")
+
+
+def test_annotate_reaches_a_recording_profiler(tmp_path):
+    """While a JAX profiler session records, ``annotate`` enters a
+    ``TraceAnnotation`` carrying its kwargs: alone with no tracer, and
+    beside the buffered span with one."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.annotate("bare", "compute", superstep=1) as a:
+            assert isinstance(a, jax.profiler.TraceAnnotation)
+        tr = trace.start()
+        with trace.annotate("both", "compute", superstep=2) as a:
+            assert isinstance(a._ann, jax.profiler.TraceAnnotation)
+    finally:
+        jax.profiler.stop_trace()
+        trace.stop()
+    assert [ev[1] for _, _, evs in tr.drain() for ev in evs] == ["both"]
 
 
 def test_stop_detaches_and_disables():

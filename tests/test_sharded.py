@@ -164,6 +164,36 @@ def test_sharded_ooc_traced_observability(tmp_path):
 
 
 @multi_device
+def test_sharded_host_legs_land_on_the_profiler_clock(tmp_path):
+    """In-memory ``run_sharded`` under ``jax.profiler``: each superstep's
+    host legs, the exchange included, are ``pregel.*`` spans with their
+    ``superstep`` stat, as under ``run_host``, and the two programs run
+    as ``superstep`` and ``exchange``."""
+    import glob
+    from jax.profiler import ProfileData
+    prog = PageRank(N, iterations=6)
+    vert = load_graph(EDGES, N, P=8, value_dims=2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run_sharded(vert, prog, prog.suggested_plan, devices=2,
+                    max_supersteps=3, on_superstep=lambda *_: None)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = ProfileData.from_file(path).find_plane_with_name("/host:CPU")
+    legs = ("pregel.dispatch", "pregel.wait", "pregel.readback",
+            "pregel.exchange", "pregel.callback")
+    got, names = {}, set()
+    for line in host.lines:
+        for e in line.events:
+            names.add(e.name)
+            if e.name in legs:
+                got.setdefault(e.name, set()).add(dict(e.stats)["superstep"])
+    assert got == {leg: {1, 2, 3} for leg in legs}
+    assert {"PjitFunction(superstep)", "PjitFunction(exchange)"} <= names
+
+
+@multi_device
 def test_sharded_regrow_spans_exchange():
     """bucket_cap=2 overflows on superstep 0 in BOTH modes; the sharded
     OOC redo must end-pad the already-landed inbox pages to the grown
