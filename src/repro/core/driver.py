@@ -14,6 +14,7 @@
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Union
@@ -70,11 +71,20 @@ def plan_gather_layout(plan: PhysicalPlan, vert: VertexRel):
     """Device-resident gather layout for the kernel path, or None when the
     resolved plan doesn't consume one. Depends only on edge_src (which the
     engine never rewrites — mutations touch edge_dst/edge_val), so one
-    layout serves a whole run; recompute only on plan switches."""
+    layout serves a whole run; recompute only on plan switches. Records
+    the ``gather.items`` and ``gather.tiles`` counters: their ratio says
+    how often a tile of the edge stream straddles a row block."""
     if not kbackend.wants_edge_layout(plan):
         return None
-    return tuple(jnp.asarray(a) for a in kbackend.plan_edge_layout(
-        np.asarray(vert.edge_src), vert.capacity))
+    layout = kbackend.plan_edge_layout(np.asarray(vert.edge_src),
+                                       vert.capacity)
+    items, tiles = kbackend.edge_layout_counts(layout, vert.num_partitions,
+                                               vert.capacity)
+    trace.counter("gather.items", items)
+    trace.counter("gather.tiles", tiles)
+    print(f"gather layout: {items} items over {tiles} tiles "
+          f"({items / tiles:.4f} a tile)", file=sys.stderr, flush=True)
+    return jnp.asarray(layout)
 
 
 @dataclass

@@ -630,7 +630,8 @@ def run_out_of_core(vert: Optional[VertexRel], program: VertexProgram,
         # is immutable for the whole run (mutations rewrite edge_dst /
         # edge_val only; commit never writes edge_src), so the cache is
         # valid across regrows AND plan switches; plan_layout_fixed pads
-        # every q's layout to the SAME shape, so the shared jitted step
+        # every q's layout to the SAME shape (load_graph sorts each
+        # partition's edges by source), so the shared jitted step
         # traces once and takes each q's layout as a plain traced argument
         gather_layouts = {}
 
@@ -639,9 +640,8 @@ def run_out_of_core(vert: Optional[VertexRel], program: VertexProgram,
                 return None
             lay = gather_layouts.get(q)
             if lay is None:
-                lay = tuple(jax.device_put(a) for a in
-                            kbackend.plan_edge_layout(
-                                store.read("edge_src", q), Np))
+                lay = jax.device_put(kbackend.plan_edge_layout(
+                    store.read("edge_src", q), Np))
                 gather_layouts[q] = lay
             return lay
 

@@ -83,17 +83,19 @@ def wants_edge_layout(plan) -> bool:
     return resolve(plan.kernel_impl) != "ref" and plan.join == "full_outer"
 
 
-def plan_edge_layout(edge_src, n_rows: int) \
-        -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+def plan_edge_layout(edge_src, n_rows: int) -> np.ndarray:
     """Host-side gather layout for a (P, Ep) edge_src block over (P, n_rows)
-    value rows. Partitions are flattened into ONE (P*Ep,) edge stream over
-    P*n_rows rows — ``pallas_call`` must not be vmapped (the batching rule
-    would regrid the kernel and break its sequential-carry assumption), so
-    a single kernel invocation serves the whole block. Uses
-    ``plan_layout_fixed``: the result shape depends only on the block's
-    shape, so every equal-shape super-partition yields an equal-shape
-    layout and the OOC driver can pass per-super-partition layouts through
-    one shared jitted superstep as traced arguments."""
+    value rows: the csr_spmv kernel's work items, one int32 word each.
+    Partitions are flattened into ONE (P*Ep,) edge stream over P*n_rows
+    rows (global row = p * n_rows + source) — ``pallas_call`` must not be
+    vmapped (the batching rule would regrid the kernel and break its
+    sequential-carry assumption), so a single kernel invocation serves
+    the whole block. Uses ``plan_layout_fixed``: for edges sorted
+    by source within each partition, as ``load_graph`` leaves them, the
+    result shape depends only on the block's shape, so every equal-shape
+    super-partition yields an equal-shape layout and the OOC driver can
+    pass per-super-partition layouts through one shared jitted superstep
+    as traced arguments."""
     from repro.kernels.csr_spmv.ops import plan_layout_fixed
     edge_src = np.asarray(edge_src)
     P, Ep = edge_src.shape
@@ -104,21 +106,30 @@ def plan_edge_layout(edge_src, n_rows: int) \
 
 
 def edge_layout_shapes(P: int, Ep: int, n_rows: int):
-    """Shapes of ``plan_edge_layout``'s result for a (P, Ep) edge block
-    over (P, n_rows) value rows, without planning it."""
-    from repro.kernels.csr_spmv.ops import layout_capacity
-    cap = layout_capacity(P * Ep, P * n_rows, block_m=GATHER_BLOCK_M,
-                          block_r=GATHER_BLOCK_R)
-    return tuple(jax.ShapeDtypeStruct(n, jnp.int32)
-                 for n in ((cap,), (P * Ep,), (cap // GATHER_BLOCK_M,)))
+    """Shape of ``plan_edge_layout``'s result for a (P, Ep) edge block
+    over (P, n_rows) value rows, sorted by source within each partition,
+    without planning it."""
+    from repro.kernels.csr_spmv.ops import item_capacity
+    cap = item_capacity(P * Ep, P * n_rows, block_m=GATHER_BLOCK_M,
+                        block_r=GATHER_BLOCK_R)
+    return jax.ShapeDtypeStruct((cap,), jnp.int32)
+
+
+def edge_layout_counts(layout, P: int, n_rows: int) -> Tuple[int, int]:
+    """(real work items, tiles) of ``plan_edge_layout``'s layout for a
+    (P, Ep) edge block over (P, n_rows) value rows."""
+    from repro.kernels.csr_spmv.ops import layout_counts
+    return layout_counts(layout, P * n_rows, block_r=GATHER_BLOCK_R)
 
 
 def edge_gather_values(values, edge_src, layout, *, impl_r: str):
     """Gather ``values[p, edge_src[p, e]]`` per edge via the csr_spmv
-    one-hot matmul kernel. values: (P, Np, V); edge_src: (P, Ep),
-    -1 = invalid; layout from ``plan_edge_layout``. Returns (P, Ep, V);
-    invalid lanes read 0.0 (masked downstream by the edge gate, exactly
-    like the clip-gather's arbitrary row-0 reads on the jnp path).
+    one-hot matmul kernel, which writes in edge order. values: (P, Np, V);
+    edge_src: (P, Ep), -1 = invalid; layout from ``plan_edge_layout``.
+    Returns (P, Ep, V); invalid lanes read 0.0 (masked downstream by the
+    edge gate, exactly like the clip-gather's arbitrary row-0 reads on the
+    jnp path). The kernel reads edge_src itself: as it is for one
+    partition, with each partition's row offset added for several.
 
     Bit-for-bit discipline: a finite value survives the one-hot matmul
     exactly (one 1.0*x product plus exact 0.0 additions; -0.0 may
@@ -131,6 +142,9 @@ def edge_gather_values(values, edge_src, layout, *, impl_r: str):
     from repro.kernels.csr_spmv.ops import gather_channels
     P, Np, V = values.shape
     Ep = edge_src.shape[1]
+    if P > 1:
+        off = jnp.arange(P, dtype=jnp.int32)[:, None] * Np
+        edge_src = jnp.where(edge_src >= 0, edge_src + off, -1)
     chans = [values[..., c].reshape(-1) for c in range(V)]
     cls = sum(jnp.where(jnp.isfinite(x), 0.0,
                         jnp.where(jnp.isnan(x), 3.0,
@@ -138,7 +152,8 @@ def edge_gather_values(values, edge_src, layout, *, impl_r: str):
               for c, x in enumerate(chans))
     table = jnp.stack([jnp.where(jnp.isfinite(x), x, 0.0) for x in chans]
                       + [cls])
-    g = gather_channels(table, layout, interpret=(impl_r != "pallas_tpu"),
+    g = gather_channels(table, edge_src.reshape(-1), layout,
+                        interpret=(impl_r != "pallas_tpu"),
                         block_m=GATHER_BLOCK_M, block_r=GATHER_BLOCK_R)
     code = g[V].astype(jnp.int32)
     out = []

@@ -1,93 +1,135 @@
-"""Jit-compatible wrapper: lays out src-sorted edges into row-block-aligned
-tiles (host-side, once per graph) and runs the Pallas gather."""
+"""Jit-compatible wrapper: plans the work items of an edge stream's tiles
+(host-side, once per graph) and runs the Pallas gather, whose output is
+already in edge order."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import backend
-from repro.kernels.csr_spmv.csr_spmv import edge_gather_pallas
+from repro.kernels.csr_spmv.csr_spmv import edge_gather_pallas, item_shift
 from repro.kernels.csr_spmv.ref import edge_gather_ref
 
 
-def plan_layout(edge_src: np.ndarray, n_rows: int, *, block_m: int = 1024,
-                block_r: int = 256):
-    """Host-side layout plan: group the edges by the row block of their
-    source and pad each block's range to a multiple of block_m slots.
-    Returns (slot_src (n_slots,) value row per slot, -1 = pad;
-    inv (E,) slot of each edge, -1 = invalid edge;
-    tile_row (n_slots // block_m,) row block of each tile)."""
+def _n_tiles(n_edge_slots: int, block_m: int) -> int:
+    return max(-(-n_edge_slots // block_m), 1)
+
+
+def _n_blocks(n_rows: int, block_r: int) -> int:
+    return max(-(-n_rows // block_r), 1)
+
+
+def _plan_items(edge_src: np.ndarray, n_rows: int, block_m: int,
+                block_r: int):
+    """(item_tile, item_row) of ``plan_layout``, in tile order then block
+    order."""
     edge_src = np.asarray(edge_src)
-    E = len(edge_src)
-    n_blocks = (n_rows + block_r - 1) // block_r
-    ok = edge_src >= 0
-    blk_ids = np.where(ok, edge_src // block_r, n_blocks)
-    order = np.argsort(blk_ids, kind="stable")
-    counts = np.bincount(blk_ids, minlength=n_blocks + 1)[:n_blocks]
-    padded = ((counts + block_m - 1) // block_m) * block_m
-    p_starts = np.concatenate([[0], np.cumsum(padded)[:-1]])
-    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-    n_slots = int(np.sum(padded)) or block_m
-    blk = blk_ids[order]
-    valid = blk < n_blocks
-    blk_c = np.minimum(blk, n_blocks - 1)
-    pos = np.arange(E) - starts[blk_c] + p_starts[blk_c]
-    slot_src = np.full(n_slots, -1, np.int32)
-    slot_src[pos[valid]] = edge_src[order[valid]]
-    inv = np.full(E, -1, np.int32)
-    inv[order[valid]] = pos[valid]
-    tile_row = np.repeat(np.arange(n_blocks), padded // block_m) \
-        .astype(np.int32)
-    if len(tile_row) == 0:
-        tile_row = np.zeros(n_slots // block_m, np.int32)
-    return slot_src, inv, tile_row
+    n_tiles = _n_tiles(len(edge_src), block_m)
+    n_blocks = _n_blocks(n_rows, block_r)
+    pos = np.flatnonzero(edge_src >= 0)
+    key = (pos // block_m) * n_blocks + edge_src[pos] // block_r
+    step = np.diff(key, prepend=-1)
+    if np.all(step >= 0):
+        key = key[step != 0]
+    else:
+        key = np.unique(key)
+    has = np.zeros(n_tiles, bool)
+    has[key // n_blocks] = True
+    key = np.sort(np.concatenate([key, np.flatnonzero(~has) * n_blocks]))
+    return ((key // n_blocks).astype(np.int32),
+            (key % n_blocks).astype(np.int32))
 
 
-def layout_capacity(n_edge_slots: int, n_rows: int, *, block_m: int = 1024,
-                    block_r: int = 256) -> int:
-    """Worst-case slot count of ``plan_layout``: each non-empty row block
-    wastes < block_m slots, so E rounded up plus one block per row block
-    always fits. A function of SHAPES only — no edge data."""
-    n_blocks = (n_rows + block_r - 1) // block_r
-    cap = ((n_edge_slots + block_m - 1) // block_m + n_blocks) * block_m
-    return max(cap, block_m)
+def plan_layout(edge_src: np.ndarray, n_rows: int, *, block_m: int = 1024,
+                block_r: int = 256) -> np.ndarray:
+    """Host-side plan of the kernel's work items. Tile t is edges
+    [block_m t, block_m (t + 1)) of the stream; its items are the distinct
+    row blocks of block_r rows that its valid edges (source >= 0) touch,
+    or block 0 alone for a tile without one. Returns the items in tile
+    order then block order, as the kernel's int32 words (``pack_items``).
+
+    A stream whose valid sources are non-decreasing (sorted by source,
+    pads anywhere) takes one pass with no sort, and has fewer than
+    ``item_capacity`` items; any other stream is sorted by key here, and
+    its item count grows with the data."""
+    return pack_items(*_plan_items(edge_src, n_rows, block_m, block_r),
+                      _n_blocks(n_rows, block_r))
+
+
+def item_capacity(n_edge_slots: int, n_rows: int, *, block_m: int = 1024,
+                  block_r: int = 256) -> int:
+    """Bound on ``plan_layout``'s item count for a stream with
+    non-decreasing valid sources: each tile has one item plus one for
+    each row block that starts inside it. A function of SHAPES only."""
+    return _n_tiles(n_edge_slots, block_m) + _n_blocks(n_rows, block_r)
+
+
+def pack_items(item_tile, item_row, n_blocks: int) -> np.ndarray:
+    """The kernel's item words, ``(i - tile) << item_shift | row block``
+    for item i."""
+    shift = item_shift(n_blocks)
+    lag = np.arange(len(item_tile), dtype=np.int64) - item_tile
+    if lag.max() >= 1 << (31 - shift):
+        raise ValueError(f"{len(item_tile)} gather items over {n_blocks} "
+                         "row blocks do not fit one int32 word each")
+    return ((lag << shift) | item_row).astype(np.int32)
+
+
+def unpack_items(items, n_blocks: int):
+    """(item_tile, item_row) of ``pack_items``' words."""
+    shift = item_shift(n_blocks)
+    items = np.asarray(items)
+    return (np.arange(len(items)) - (items >> shift)).astype(np.int32), \
+        items & ((1 << shift) - 1)
 
 
 def plan_layout_fixed(edge_src: np.ndarray, n_rows: int, *,
                       block_m: int = 1024, block_r: int = 256):
-    """``plan_layout`` padded to shapes that depend ONLY on
-    (len(edge_src), n_rows, block_m, block_r) — never on where the edges
-    actually point. Equal-shape edge blocks therefore produce equal-shape
-    layouts, which is what lets a layout be a TRACED argument of one
-    shared jitted superstep (the out-of-core driver reuses a single
-    compiled step across super-partitions, each with its own layout).
-    Pad slots carry slot_src = -1 and tile_row = 0 (they gather 0.0)."""
-    slot_src, inv, tile_row = plan_layout(edge_src, n_rows,
-                                          block_m=block_m, block_r=block_r)
-    cap = layout_capacity(len(edge_src), n_rows, block_m=block_m,
-                          block_r=block_r)
-    slot_f = np.full(cap, -1, np.int32)
-    slot_f[:len(slot_src)] = slot_src
-    tile_f = np.zeros(cap // block_m, np.int32)
-    tile_f[:len(tile_row)] = tile_row
-    return slot_f, inv, tile_f
+    """``plan_layout`` padded to ``item_capacity`` items by repeating the
+    last real item (which rewrites the same values), as the kernel's
+    packed words. For streams with non-decreasing valid sources the shape
+    then depends ONLY on (len(edge_src), n_rows, block_m, block_r), which
+    lets a layout be a TRACED argument of one shared jitted superstep (the
+    out-of-core driver reuses a single compiled step across
+    super-partitions, each with its own layout). Any other stream may
+    need more items, and keeps them."""
+    item_tile, item_row = _plan_items(edge_src, n_rows, block_m, block_r)
+    cap = item_capacity(len(edge_src), n_rows, block_m=block_m,
+                        block_r=block_r)
+    pad = max(cap - len(item_tile), 0)
+    return pack_items(np.pad(item_tile, (0, pad), mode="edge"),
+                      np.pad(item_row, (0, pad), mode="edge"),
+                      _n_blocks(n_rows, block_r))
 
 
-def gather_channels(table, layout, *, interpret: bool, block_m: int = 1024,
-                    block_r: int = 256):
-    """table: (C, N) channel-major values; layout from ``plan_layout``.
-    -> (C, E): table[:, src[e]] per edge, 0.0 for invalid edges."""
-    slot_src, inv, tile_row = layout
+def layout_counts(items, n_rows: int, *, block_r: int = 256) -> tuple:
+    """(real items, tiles) of a ``plan_layout_fixed`` layout: real items
+    are distinct, and the padding repeats the last of them."""
+    item_tile, item_row = unpack_items(items, _n_blocks(n_rows, block_r))
+    pads = np.count_nonzero((item_tile[1:] == item_tile[:-1])
+                            & (item_row[1:] == item_row[:-1]))
+    return len(item_tile) - int(pads), int(item_tile[-1]) + 1
+
+
+def gather_channels(table, edge_src, layout, *, interpret: bool,
+                    block_m: int = 1024, block_r: int = 256):
+    """table: (C, N) channel-major values; edge_src: (E,) value row per
+    edge, -1 = invalid; layout from ``plan_layout`` or
+    ``plan_layout_fixed`` over that stream and N rows. -> (C, E):
+    table[:, src[e]] per edge, 0.0 for invalid edges. The stream is
+    copied only to pad it to whole tiles."""
     C, N = table.shape
     C8 = -(-C // 8) * 8
     tab = jnp.pad(table.astype(jnp.float32),
                   ((0, C8 - C), (0, (-N) % block_r)))
-    out = edge_gather_pallas(tab, jnp.asarray(slot_src),
-                             jnp.asarray(tile_row), C, block_m=block_m,
-                             block_r=block_r, interpret=interpret)
-    inv = jnp.asarray(inv)
-    ok, idx = inv >= 0, inv.clip(0)
-    return jnp.stack([jnp.where(ok, out[c][idx], 0.0) for c in range(C)])
+    E = edge_src.shape[0]
+    pad = _n_tiles(E, block_m) * block_m - E
+    if pad:
+        edge_src = jnp.pad(edge_src, (0, pad), constant_values=-1)
+    out = edge_gather_pallas(tab, edge_src, jnp.asarray(layout), C,
+                             block_m=block_m, block_r=block_r,
+                             interpret=interpret)
+    return out[:, :E] if pad else out
 
 
 def edge_gather(values, edge_src, edge_val, *, layout=None,
@@ -97,7 +139,8 @@ def edge_gather(values, edge_src, edge_val, *, layout=None,
     impl_r = backend.resolve(impl)
     if impl_r == "ref" or layout is None:
         return edge_gather_ref(values, edge_src, edge_val)
-    g = gather_channels(values.T, layout, block_m=block_m, block_r=block_r,
+    g = gather_channels(values.T, edge_src, layout, block_m=block_m,
+                        block_r=block_r,
                         interpret=(impl_r != "pallas_tpu"))
     return jnp.where((edge_src >= 0)[:, None], g.T * edge_val[:, None],
                      0.0)

@@ -20,7 +20,7 @@ from repro.core.superstep import EngineConfig, make_superstep
 from repro.graph import SSSP, PageRank
 from repro.kernels import backend as kbackend
 from repro.kernels.csr_spmv.csr_spmv import edge_gather_pallas
-from repro.kernels.csr_spmv.ops import layout_capacity
+from repro.kernels.csr_spmv.ops import item_capacity
 from repro.kernels.segment_combine.segment_combine import fold_lane_dense
 
 EDGE_SLOTS = 1 << 22
@@ -51,14 +51,15 @@ def _sds(shape, dtype, sharding):
 
 
 def test_csr_spmv_compiles_for_v5e(one_chip):
-    n_rows, block_m = 1 << 20, kbackend.GATHER_BLOCK_M
-    f = jax.jit(lambda t, s, r: edge_gather_pallas(
-        t, s, r, 3, block_m=block_m, block_r=kbackend.GATHER_BLOCK_R,
+    n_rows, block_r = 1 << 20, kbackend.GATHER_BLOCK_R
+    items = item_capacity(EDGE_SLOTS, n_rows,
+                          block_m=kbackend.GATHER_BLOCK_M, block_r=block_r)
+    f = jax.jit(lambda t, s, w: edge_gather_pallas(
+        t, s, w, 3, block_m=kbackend.GATHER_BLOCK_M, block_r=block_r,
         interpret=False))
     compiled = f.lower(_sds((8, n_rows), jnp.float32, one_chip),
                        _sds((EDGE_SLOTS,), jnp.int32, one_chip),
-                       _sds((EDGE_SLOTS // block_m,), jnp.int32,
-                            one_chip)).compile()
+                       _sds((items,), jnp.int32, one_chip)).compile()
     assert KERNEL in compiled.as_text()
 
 
@@ -97,19 +98,19 @@ def _superstep_args(program, plan, scale: int, sharding):
                      overflow=s((N_OVERFLOW,), jnp.int32),
                      active_count=s((), jnp.int32),
                      msg_count=s((), jnp.int32))
-    cap = layout_capacity(P * Ep, P * Np, block_m=kbackend.GATHER_BLOCK_M,
-                          block_r=kbackend.GATHER_BLOCK_R)
-    layout = (s((cap,), jnp.int32), s((P * Ep,), jnp.int32),
-              s((cap // kbackend.GATHER_BLOCK_M,), jnp.int32))
+    items = kbackend.edge_layout_shapes(P, Ep, Np)
+    layout = s(items.shape, items.dtype)
     return ec, (vert, msg, gs, None, layout), P * Ep
 
 
 @pytest.mark.parametrize("algo", ["pagerank", "sssp"])
 def test_superstep_compiles_for_v5e_and_fits(one_chip, algo):
     """One whole superstep on the kernel path that ``auto`` takes on a
-    TPU: both kernels compile in, and the temporaries stay a few dozen
+    TPU: both kernels compile in, the temporaries stay a few dozen
     bytes per edge slot (a (P, Ep, V) gather index once padded each slot
-    to a tile, ~1 KB per slot for PageRank)."""
+    to a tile, ~1 KB per slot for PageRank), and the arguments stay near
+    the edge relation's 12 bytes per slot: the gather layout has no
+    per-edge array (one would add 4 bytes per slot)."""
     scale = 14
     program = PageRank(1 << scale) if algo == "pagerank" else SSSP(0)
     plan = PhysicalPlan(join="full_outer", kernel_impl="pallas_tpu")
@@ -117,5 +118,8 @@ def test_superstep_compiles_for_v5e_and_fits(one_chip, algo):
     compiled = jax.jit(make_superstep(program, plan, ec)).lower(
         *args).compile()
     assert compiled.as_text().count(KERNEL) == 2
-    temp_per_slot = compiled.memory_analysis().temp_size_in_bytes / slots
+    mem = compiled.memory_analysis()
+    temp_per_slot = mem.temp_size_in_bytes / slots
     assert temp_per_slot < 64, f"{temp_per_slot:.0f} B per edge slot"
+    arg_per_slot = mem.argument_size_in_bytes / slots
+    assert arg_per_slot < 14, f"{arg_per_slot:.2f} B per edge slot"
