@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import sys
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Union
 
@@ -67,24 +68,56 @@ def apply_kernel_impl(plan: PlanArg, kernel_impl: Optional[str],
     return plan, auto_space
 
 
-def plan_gather_layout(plan: PhysicalPlan, vert: VertexRel):
+def plan_gather_layout(plan: PhysicalPlan, vert: VertexRel, mesh=None):
     """Device-resident gather layout for the kernel path, or None when the
     resolved plan doesn't consume one. Depends only on edge_src (which the
     engine never rewrites — mutations touch edge_dst/edge_val), so one
     layout serves a whole run; recompute only on plan switches. Records
     the ``gather.items`` and ``gather.tiles`` counters: their ratio says
-    how often a tile of the edge stream straddles a row block."""
+    how often a tile of the edge stream straddles a row block.
+
+    With ``mesh`` (``run_sharded``; ``vert`` sharded over it by its
+    leading axis), each worker's layout is planned from its own block of
+    partitions, read from its own device, under one ``pregel.layout``
+    span. The N layouts, padded to one length, stack to (N, cap), and
+    each worker's row is placed on that worker's device alone; the
+    counters carry the worker as a suffix (``gather.items.w<k>``)."""
     if not kbackend.wants_edge_layout(plan):
         return None
-    layout = kbackend.plan_edge_layout(np.asarray(vert.edge_src),
-                                       vert.capacity)
-    items, tiles = kbackend.edge_layout_counts(layout, vert.num_partitions,
-                                               vert.capacity)
-    trace.counter("gather.items", items)
-    trace.counter("gather.tiles", tiles)
-    print(f"gather layout: {items} items over {tiles} tiles "
+    if mesh is None:
+        layout = kbackend.plan_edge_layout(np.asarray(vert.edge_src),
+                                           vert.capacity)
+        _count_layout(layout, vert.num_partitions, vert.capacity, "")
+        return jnp.asarray(layout)
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as PSpec
+    axes = tuple(mesh.axis_names)
+    devices = list(mesh.devices.flat)
+    P_w = vert.num_partitions // len(devices)
+    with trace.annotate("pregel.layout", "prepare", workers=len(devices)):
+        blocks = {s.device: s.data for s in vert.edge_src.addressable_shards}
+        # NumPy releases the GIL: the workers' blocks plan side by side
+        with ThreadPoolExecutor(len(devices)) as pool:
+            layouts = list(pool.map(
+                lambda dev: kbackend.plan_edge_layout(
+                    np.asarray(blocks[dev]), vert.capacity), devices))
+        for w, layout in enumerate(layouts):
+            _count_layout(layout, P_w, vert.capacity, f".w{w}")
+        cap = max(len(x) for x in layouts)
+        rows = [jax.device_put(kbackend.pad_edge_layout(
+            x, cap, P_w, vert.capacity)[None], dev)
+            for x, dev in zip(layouts, devices)]
+        return jax.make_array_from_single_device_arrays(
+            (len(devices), cap), NamedSharding(mesh, PSpec(axes, None)),
+            rows)
+
+
+def _count_layout(layout, P: int, n_rows: int, suffix: str) -> None:
+    items, tiles = kbackend.edge_layout_counts(layout, P, n_rows)
+    trace.counter("gather.items" + suffix, items)
+    trace.counter("gather.tiles" + suffix, tiles)
+    print(f"gather layout{suffix}: {items} items over {tiles} tiles "
           f"({items / tiles:.4f} a tile)", file=sys.stderr, flush=True)
-    return jnp.asarray(layout)
 
 
 @dataclass
@@ -238,6 +271,10 @@ def run_host(vert: VertexRel, program: VertexProgram,
     planner: the initial plan is chosen for superstep 0's all-active
     frontier and re-chosen at superstep boundaries as observed frontier
     density crosses the model's thresholds (planner.adaptive).
+
+    ``on_superstep(i, vert, msg, gs, stats_dict)`` is called after
+    superstep i; a return value that ``is True`` ends the run there, and
+    ``RunResult.vertex`` holds the state of that boundary.
 
     ``resume_from=<ckpt npz>`` restarts from a checkpoint (optionally
     re-hashed onto ``resume_parts`` partitions — the elastic restore).
@@ -450,7 +487,9 @@ def run_host(vert: VertexRel, program: VertexProgram,
                 save_checkpoint(checkpoint_dir, i, vert, msg, gs)
         if on_superstep is not None:
             with host_leg("callback", n):
-                on_superstep(i, vert, msg, gs, rec.as_dict())
+                stop = on_superstep(i, vert, msg, gs, rec.as_dict()) is True
+            if stop:
+                break
         with host_leg("readback", n):
             halted = bool(gs.halt)
         if halted:

@@ -13,11 +13,16 @@ dst vid, value) arrays — the CSR adaptation for edge-parallel sends.
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# with sources sorted, up to this many partitions are picked by one mask
+# each (a pass over the edges apiece) in place of a sort by owner
+_MASKED_PARTITIONS = 8
 
 
 @jax.tree_util.register_dataclass
@@ -93,7 +98,8 @@ def init_gs(agg_dims: int) -> GlobalState:
 def load_graph(edges: np.ndarray, num_vertices: int, P: int, *,
                value_dims: int, edge_values: np.ndarray | None = None,
                capacity_factor: float = 1.3,
-               partition: str = "hash") -> VertexRel:
+               partition: str = "hash", mesh=None,
+               edge_capacity: int = 0) -> VertexRel:
     """Partition an edge list (E, 2) into a VertexRel (the paper's bulk
     load: scan, partition by vid, sort, bulk-load per-partition indexes).
 
@@ -101,49 +107,152 @@ def load_graph(edges: np.ndarray, num_vertices: int, P: int, *,
     partition="range": vid lives at (vid // cap, vid % cap) — owners are
     contiguous in vid order (see PhysicalPlan.partition); capacity_factor
     is forced to 1.0 (no insert headroom).
-    """
-    edges = np.asarray(edges, dtype=np.int64)
-    if partition == "range":
-        capacity_factor = 1.0
-    Np = int(np.ceil(num_vertices / P) * capacity_factor) + 1
 
-    def owner_slot(v):
+    Each partition keeps its edges sorted by source, ties in input order,
+    in ``Ep`` slots: the largest partition's edge count, or
+    ``edge_capacity`` where that is more. A caller that loads graphs of
+    one size but different draws fixes ``edge_capacity`` above their
+    largest partitions, so every load has one shape and the programs
+    compiled for one serve the next (``vertex_rel_shapes`` gives that
+    shape before the load). Without ``mesh`` the relation lands on the
+    default device. With a ``mesh`` of N devices (P divisible by N), the
+    partitions shard over it in contiguous blocks, the layout
+    ``run_sharded`` runs: each device's block [w P/N, (w+1) P/N) is built
+    on the host and placed on that device alone, under a ``pregel.place``
+    span with its ``bytes``, so no device ever holds another's block.
+    """
+    edges = np.asarray(edges)
+    if edges.dtype not in (np.int32, np.int64):
+        edges = edges.astype(np.int64)
+    Np = _rows_per_partition(num_vertices, P, capacity_factor, partition)
+
+    def owner_of(v):
         if partition == "range":
-            o = np.minimum(v // Np, P - 1)
-            return o, v - o * Np
-        return v % P, v // P
+            return np.minimum(v // Np, P - 1)
+        return v % P
+
+    def slot_of(v):
+        if partition == "range":
+            return v - owner_of(v) * Np
+        return v // P
 
     vid = np.full((P, Np), -1, np.int32)
-    halt = np.zeros((P, Np), bool)
-    value = np.zeros((P, Np, value_dims), np.float32)
     all_v = np.arange(num_vertices, dtype=np.int64)
-    po, ps = owner_slot(all_v)
-    vid[po, ps] = all_v.astype(np.int32)
+    vid[owner_of(all_v), slot_of(all_v)] = all_v.astype(np.int32)
 
     src, dst = edges[:, 0], edges[:, 1]
     ev = (np.asarray(edge_values, np.float32) if edge_values is not None
-          else np.ones(len(src), np.float32))
-    owner, slot = owner_slot(src)
-    order = np.argsort(owner * (num_vertices + 1) + src, kind="stable")
-    src, dst, ev = src[order], dst[order], ev[order]
-    owner, slot = owner[order], slot[order]
+          else None)
+    with ThreadPoolExecutor(min(P, 8)) as pool:
+        picks, counts = _partition_picks(
+            src, P, num_vertices, owner_of, pool)
+        Ep = max(max(counts), edge_capacity, 1)
+
+        def block(parts: range) -> dict:
+            """Host arrays of partitions ``parts``."""
+            e_src = np.full((len(parts), Ep), -1, np.int32)
+            e_dst = np.full((len(parts), Ep), -1, np.int32)
+            e_val = np.zeros((len(parts), Ep), np.float32)
+            for k, p in enumerate(parts):
+                e_src[k, :counts[p]] = slot_of(src[picks[p]])
+                e_dst[k, :counts[p]] = dst[picks[p]]
+                e_val[k, :counts[p]] = 1.0 if ev is None else ev[picks[p]]
+            return dict(vid=vid[parts.start:parts.stop],
+                        halt=np.zeros((len(parts), Np), bool),
+                        value=np.zeros((len(parts), Np, value_dims),
+                                       np.float32),
+                        edge_src=e_src, edge_dst=e_dst, edge_val=e_val)
+
+        if mesh is None:
+            return VertexRel(**{f: jnp.asarray(a)
+                                for f, a in block(range(P)).items()})
+        return _place_blocks(mesh, P, block, pool)
+
+
+def _rows_per_partition(num_vertices: int, P: int, capacity_factor: float,
+                        partition: str) -> int:
+    """``Np``: the vertex rows of each partition ``load_graph`` builds."""
+    if partition == "range":
+        capacity_factor = 1.0
+    return int(np.ceil(num_vertices / P) * capacity_factor) + 1
+
+
+def vertex_rel_shapes(num_vertices: int, P: int, *, value_dims: int,
+                      edge_slots: int, capacity_factor: float = 1.3,
+                      partition: str = "hash", mesh=None) -> VertexRel:
+    """The relation ``load_graph(..., edge_capacity=edge_slots)`` returns
+    for a graph whose partitions hold at most ``edge_slots`` edges each,
+    as ``jax.ShapeDtypeStruct`` leaves, sharded over ``mesh`` by their
+    leading axis as ``load_graph`` places them there."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as PSpec
+
+    Np = _rows_per_partition(num_vertices, P, capacity_factor, partition)
+
+    def leaf(shape, dtype):
+        sharding = None if mesh is None else NamedSharding(
+            mesh, PSpec(tuple(mesh.axis_names), *[None] * (len(shape) - 1)))
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    rows, edges = (P, Np), (P, edge_slots)
+    return VertexRel(vid=leaf(rows, jnp.int32), halt=leaf(rows, jnp.bool_),
+                     value=leaf(rows + (value_dims,), jnp.float32),
+                     edge_src=leaf(edges, jnp.int32),
+                     edge_dst=leaf(edges, jnp.int32),
+                     edge_val=leaf(edges, jnp.float32))
+
+
+def _partition_picks(src, P: int, num_vertices: int, owner_of, pool):
+    """Per partition, the index (a slice, a mask or positions) that picks
+    its edges out of the input in the order it keeps them, sorted by
+    source with ties in input order, and their counts."""
+    presorted = len(src) > 0 and bool(np.all(src[1:] >= src[:-1]))
+    if presorted and P == 1:
+        return [slice(None)], [len(src)]
+    owner = owner_of(src)
+    if presorted and P <= _MASKED_PARTITIONS:
+        # a partition's edges are the ones its mask picks, in input order
+        # (NumPy releases the GIL, so the masks fill side by side)
+        picks = list(pool.map(lambda p: owner == p, range(P)))
+        return picks, [int(np.count_nonzero(m)) for m in picks]
+    order = np.argsort(owner.astype(np.int64) * (num_vertices + 1) + src,
+                       kind="stable")
     counts = np.bincount(owner, minlength=P)
-    Ep = int(max(counts.max(), 1))
-    e_src = np.full((P, Ep), -1, np.int32)
-    e_dst = np.full((P, Ep), -1, np.int32)
-    e_val = np.zeros((P, Ep), np.float32)
-    start = 0
-    for p in range(P):
-        c = counts[p]
-        e_src[p, :c] = slot[start:start + c].astype(np.int32)
-        e_dst[p, :c] = dst[start:start + c].astype(np.int32)
-        e_val[p, :c] = ev[start:start + c]
-        start += c
-    return VertexRel(vid=jnp.asarray(vid), halt=jnp.asarray(halt),
-                     value=jnp.asarray(value),
-                     edge_src=jnp.asarray(e_src),
-                     edge_dst=jnp.asarray(e_dst),
-                     edge_val=jnp.asarray(e_val))
+    start = np.concatenate([[0], np.cumsum(counts)])
+    return ([order[start[p]:start[p + 1]] for p in range(P)],
+            counts.tolist())
+
+
+def _place_blocks(mesh, P: int, block, pool) -> VertexRel:
+    """A VertexRel sharded over ``mesh`` by its leading (partition) axis,
+    each device's block made by ``block(range of its partitions)`` and put
+    on that device alone, the devices' blocks side by side on ``pool``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as PSpec
+
+    from repro.obs import trace
+    axes = tuple(mesh.axis_names)
+    N = int(mesh.devices.size)
+    if P % N:
+        raise ValueError(f"{P} partitions do not divide over {N} devices")
+    rows = NamedSharding(mesh, PSpec(axes)).devices_indices_map((P,))
+    devices = list(mesh.devices.flat)
+
+    def place(w: int) -> dict:
+        sl = rows[devices[w]][0]
+        blk = block(range(sl.start or 0, P if sl.stop is None else sl.stop))
+        with trace.annotate("pregel.place", "prepare", worker=w,
+                            bytes=sum(a.nbytes for a in blk.values())):
+            return jax.block_until_ready(
+                {f: jax.device_put(a, devices[w]) for f, a in blk.items()})
+
+    shards = list(pool.map(place, range(N)))
+    return VertexRel(**{
+        f: jax.make_array_from_single_device_arrays(
+            (P,) + a.shape[1:],
+            NamedSharding(mesh, PSpec(axes, *[None] * (a.ndim - 1))),
+            [s[f] for s in shards])
+        for f, a in shards[0].items()})
 
 
 def out_degrees(vert: VertexRel) -> jax.Array:

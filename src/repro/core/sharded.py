@@ -14,14 +14,17 @@ the emulated transport (``tests/test_sharded.py``).
 Two modes:
 
 * **In-memory** (default): one shard_map-wrapped jitted superstep per
-  iteration, with the message exchange split out as its OWN jitted
-  all_to_all stage (``EngineConfig.exchange_apart``) so the driver can
-  time it — each superstep records an ``exchange`` span plus
-  ``exchange_bytes`` / ``exchange_stall_s`` counters, the measurements
-  behind the planner's network axis (``MachineModel.net_bw``,
-  ``Observation.net_scale``). GS folds via the superstep's own psum
-  reductions; vote-to-halt, overflow-regrow, adaptive replanning and
-  frontier refit all work exactly as in ``run_host``.
+  iteration (program ``jit_superstep``), with the message exchange split
+  out as its OWN jitted all_to_all stage (``EngineConfig.exchange_apart``,
+  program ``jit_exchange``) so the driver can time it — each superstep
+  records a ``pregel.exchange`` span plus ``exchange_bytes`` /
+  ``exchange_stall_s`` counters, the measurements behind the planner's
+  network axis (``MachineModel.net_bw``, ``Observation.net_scale``). On
+  the kernel path (``kbackend.wants_edge_layout``) each worker gathers
+  its edges with ``csr_spmv`` through a gather layout planned from its
+  own block. GS folds via the superstep's own psum reductions;
+  vote-to-halt, overflow-regrow, adaptive replanning and frontier refit
+  all work exactly as in ``run_host``.
 
 * **Out-of-core** (``budget_partitions`` set): every worker gets its OWN
   ``TieredStore`` (+ background ``IOEngine`` when a disk dir is set, at
@@ -45,6 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional
 
 import jax
@@ -57,16 +61,18 @@ from repro.core import connector
 from repro.core.driver import (PlanArg, RunResult, _regrow_msgs,
                                _resolve_plan, apply_kernel_impl,
                                default_engine_config, grow_overflowed,
-                               host_leg, init_vertex_values)
+                               host_leg, init_vertex_values,
+                               plan_gather_layout)
 from repro.core.plan import FRONTIER_FLOOR, PhysicalPlan
 from repro.core.program import VertexProgram
 from repro.core.relations import (GlobalState, MsgRel, VertexRel,
                                   empty_msgs, init_gs)
 from repro.core.superstep import ROUTE, EngineConfig, make_superstep
+from repro.kernels import backend as kbackend
 from repro.obs import explain, memwatch, trace
 from repro.obs.metrics import MetricsRegistry
 
-_MSG_W = lambda D: (1 + D) * 4 + 1   # dst + payload + valid wire bytes
+_MSG_W = lambda D: (1 + D) * 4 + 4   # dst + payload + valid (as int32)
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
@@ -79,6 +85,157 @@ def _shard_map(fn, mesh, in_specs, out_specs):
 def _lead_spec(axes):
     """Leading-axis sharding spec builder: dim 0 over the mesh axes."""
     return lambda x: PSpec(*([axes] + [None] * (len(x.shape) - 1)))
+
+
+def sharded_superstep(program: VertexProgram, plan: PhysicalPlan,
+                      ec: EngineConfig, mesh, *, layout: bool = False):
+    """The in-memory sharded superstep as two jitted ``shard_map``
+    programs over ``mesh``: ``superstep(vert, msg, gs[, layouts])`` (with
+    ``ec.exchange_apart``, it returns the pre-exchange buckets as its
+    messages) and ``exchange(buckets)``, the all_to_all. With ``layout``,
+    the superstep takes the stacked (N, cap) per-worker gather layouts of
+    ``plan_gather_layout(..., mesh=mesh)`` and hands each worker its own
+    row."""
+    axes = tuple(mesh.axis_names)
+    fn = make_superstep(program, plan, ec)
+
+    # named, so the profiler names the programs ``jit_superstep`` and
+    # ``jit_exchange``, as the trace readers look for them
+    if layout:
+        def superstep(v, m, g, lay):
+            return fn(v, m, g, None, lay[0])
+    else:
+        def superstep(v, m, g):
+            return fn(v, m, g, None, None)
+
+    # out_specs are written by hand: the body contains psums over the
+    # mesh axes, so eval_shape outside shard_map would fail on the
+    # unbound axis names
+    rows, deep = PSpec(axes, None), PSpec(axes, None, None)
+    v_specs = VertexRel(vid=rows, halt=rows, value=deep, edge_src=rows,
+                        edge_dst=rows, edge_val=rows)
+    m_specs = MsgRel(dst=rows, payload=deep, valid=rows)
+    g_specs = jax.tree.map(lambda _: PSpec(), init_gs(program.agg_dims))
+    bkt_specs = MsgRel(dst=deep, payload=PSpec(axes, None, None, None),
+                       valid=deep)
+    in_specs = (v_specs, m_specs, g_specs) + ((rows,) if layout else ())
+    out_specs = (v_specs, bkt_specs, g_specs)
+    step = jax.jit(_shard_map(superstep, mesh, in_specs, out_specs))
+
+    @jax.named_scope(ROUTE)
+    def exchange(m: MsgRel) -> MsgRel:
+        r_dst, r_pay, r_val = connector.exchange_shard_map(
+            m.dst, m.payload, m.valid, axes)
+        P_l = m.dst.shape[0]
+        flat = lambda a: a.reshape((P_l, -1) + a.shape[3:])
+        return MsgRel(dst=flat(r_dst), payload=flat(r_pay),
+                      valid=flat(r_val))
+
+    ex = jax.jit(_shard_map(exchange, mesh, (bkt_specs,), m_specs))
+    return step, ex
+
+
+def _compile_first(program: VertexProgram, plan: PhysicalPlan,
+                   ec: EngineConfig, mesh, vert: VertexRel):
+    """``sharded_superstep``'s programs for ``plan``/``ec`` compiled ahead
+    of a fresh run's first superstep, from the shapes it will take: the
+    relation as ``init_vertex_values`` leaves it, empty messages, the
+    initial global state and, on the kernel path, the (N, cap) layouts of
+    blocks sorted by source (``kbackend.edge_layout_shapes``). Returns
+    (superstep, exchange, the superstep's argument specs, and the
+    (program, plan, ec) they were compiled for)."""
+    axes = tuple(mesh.axis_names)
+    N = int(mesh.devices.size)
+    lead = _lead_spec(axes)
+
+    def spec(tree, pspec):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, pspec(x))), tree)
+
+    gs = init_gs(program.agg_dims)
+    args = [spec(jax.eval_shape(
+                lambda v: init_vertex_values(v, program, gs), vert), lead),
+            spec(jax.eval_shape(lambda: empty_msgs(
+                vert.num_partitions, ec.n_parts * ec.bucket_cap,
+                program.msg_dims)), lead),
+            spec(jax.eval_shape(lambda: gs), lambda _: PSpec())]
+    layout = kbackend.wants_edge_layout(plan)
+    if layout:
+        cap = kbackend.edge_layout_shapes(
+            vert.num_partitions // N, vert.edge_src.shape[1],
+            vert.capacity).shape[0]
+        args.append(jax.ShapeDtypeStruct(
+            (N, cap), jnp.int32, sharding=NamedSharding(mesh,
+                                                        PSpec(axes, None))))
+    step, ex = sharded_superstep(program, plan, ec, mesh, layout=layout)
+    lowered = step.lower(*args)
+    step = lowered.compile()
+    buckets = jax.tree.map(
+        lambda o, s: jax.ShapeDtypeStruct(o.shape, o.dtype, sharding=s),
+        lowered.out_info[1], step.output_shardings[1])
+    return step, ex.lower(buckets).compile(), args, (program, plan, ec)
+
+
+def _resolve_sharded(vert, program: VertexProgram, plan: PhysicalPlan,
+                     ec: Optional[EngineConfig], axes) -> EngineConfig:
+    """The engine configuration of a fresh in-memory run of ``plan``."""
+    ec = ec or default_engine_config(vert, program, plan)
+    return dataclasses.replace(ec, axis_name=axes, exchange_apart=True)
+
+
+def _submit(fn, *args):
+    """``fn(*args)`` on a thread of its own; returns its Future."""
+    pool = ThreadPoolExecutor(1)
+    try:
+        return pool.submit(fn, *args)
+    finally:
+        pool.shutdown(wait=False)
+
+
+def compile_ahead(vert: VertexRel, program: VertexProgram,
+                  plan: PhysicalPlan, *, mesh,
+                  kernel_impl: Optional[str] = None,
+                  ec: Optional[EngineConfig] = None):
+    """Start compiling the first superstep and exchange of
+    ``run_sharded(vert, program, plan, mesh=mesh, kernel_impl=..., ec=...)``
+    on a thread, from ``vert``'s shapes alone (a relation, or
+    ``vertex_rel_shapes``' description of the one ``load_graph`` will
+    return), so a caller can compile while it loads the graph. Returns
+    the Future that ``run_sharded(..., ahead=)`` takes; a run whose
+    program, plan, engine configuration or arguments differ from what was
+    compiled ignores it and compiles its own."""
+    if not isinstance(plan, PhysicalPlan):
+        raise ValueError("compile_ahead needs a concrete PhysicalPlan")
+    plan, _ = apply_kernel_impl(plan, kernel_impl, None)
+    ec = _resolve_sharded(vert, program, plan, ec, tuple(mesh.axis_names))
+    return _submit(_compile_first, program, plan, ec, mesh, vert)
+
+
+def _use_compiled(ahead, program, plan, ec, state, layout, step, exchange):
+    """The programs ``_compile_first`` compiled, bound to ``layout``, when
+    they were compiled for ``program``, ``plan`` and ``ec`` and the first
+    superstep's arguments are what they were compiled for; else (or if
+    compiling ahead failed: the jitted programs then compile on their
+    first call and raise what there is to raise) ``step`` and
+    ``exchange`` unchanged."""
+    try:
+        c_step, c_ex, specs, made_for = ahead.result()
+    except Exception:  # noqa: BLE001
+        return step, exchange
+    if made_for[0] is not program or made_for[1:] != (plan, ec):
+        return step, exchange
+    args = list(state) + ([] if layout is None else [layout])
+    same = len(args) == len(specs) and all(
+        jax.tree.structure(a) == jax.tree.structure(s) and all(
+            x.shape == y.shape and x.dtype == y.dtype
+            and x.sharding.is_equivalent_to(y.sharding, x.ndim)
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(s)))
+        for a, s in zip(args, specs))
+    if not same:
+        return step, exchange
+    if layout is None:
+        return c_step, c_ex
+    return (lambda v, m, g, lay=layout: c_step(v, m, g, lay)), c_ex
 
 
 def _exchange_wire_bytes(P: int, n_parts: int, C: int, D: int,
@@ -157,13 +314,28 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
                 resume_from: Optional[str] = None,
                 recover: bool = False,
                 max_retries: int = 3,
-                machine=None) -> RunResult:
+                machine=None, ahead=None) -> RunResult:
     """Run `program` on a device mesh. ``mesh`` (or ``devices`` for a 1-D
     host mesh) sets the worker count N; the P partitions shard over it in
-    contiguous blocks. With ``budget_partitions`` set, each worker
-    streams its block through the device ``budget_partitions`` at a time
-    from its own tiered store (per-worker OOC). ``on_superstep`` is
-    called as ``on_superstep(i, stats_dict)``.
+    contiguous blocks: worker w owns [w P/N, (w+1) P/N). A relation that
+    ``load_graph(..., mesh=mesh)`` already placed so is used where it
+    lies, without another copy; any other is placed block by block. With
+    ``budget_partitions`` set, each worker streams its block through the
+    device ``budget_partitions`` at a time from its own tiered store
+    (per-worker OOC).
+
+    ``kernel_impl`` / a plan's ``kernel_impl`` choose the kernels as in
+    ``run_host`` (``auto``: compiled Pallas on a TPU, the jnp reference
+    elsewhere). On the in-memory kernel path each worker's ``csr_spmv``
+    gather runs on a layout planned from its own block
+    (``plan_gather_layout``), re-planned on a plan switch. A fresh
+    in-memory run compiles its first superstep and exchange on a thread
+    while the workers initialize and plan their layouts, or takes them
+    from ``ahead``, the Future of an earlier ``compile_ahead`` call.
+
+    ``on_superstep`` is called as ``on_superstep(i, stats_dict)`` after
+    superstep i; in-memory, a return value that ``is True`` ends the run
+    there, and ``RunResult.vertex`` holds the state of that boundary.
 
     ``checkpoint_every``/``checkpoint_dir`` snapshot the gathered global
     relations as npz at superstep boundaries (in-memory mode only);
@@ -245,19 +417,12 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
                 f"sharded driver resumes at a fixed P={P}")
         i0 = int(rgs.superstep)
     plan, auto_space = apply_kernel_impl(plan, kernel_impl, auto_space)
-    if not isinstance(plan, PhysicalPlan):
-        # pin the kernel dispatch to the jnp reference inside shard_map
-        # unless the caller asked for something else (pallas_call under
-        # shard_map is untested here)
-        auto_space = dict(auto_space or {})
-        auto_space.setdefault("kernel_impls", ("ref",))
     obs0 = Observation(frontier_density=1.0, sharded=True, n_workers=N)
     plan, controller = _resolve_plan(vert, program, plan, adaptive=True,
                                      auto_config=auto_config,
                                      auto_space=auto_space,
                                      machine=machine, obs0=obs0)
-    ec = ec or default_engine_config(vert, program, plan)
-    ec = dataclasses.replace(ec, axis_name=axes, exchange_apart=True)
+    ec = _resolve_sharded(vert, program, plan, ec, axes)
     if rmsg is not None and rmsg.capacity > ec.n_parts * ec.bucket_cap:
         ec = dataclasses.replace(
             ec, bucket_cap=-(-rmsg.capacity // ec.n_parts))
@@ -273,56 +438,31 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
                            msg_dims=program.msg_dims)
 
     lead = _lead_spec(axes)
-    rep = lambda x: PSpec()
+    # device_put onto the sharding an array already has is a no-op
     put_lead = lambda tree: jax.tree.map(
         lambda x: jax.device_put(x, NamedSharding(mesh, lead(x))), tree)
     put_rep = lambda tree: jax.tree.map(
         lambda x: jax.device_put(x, NamedSharding(mesh, PSpec())), tree)
 
     def build_step(plan, ec):
-        """shard_map-wrapped jitted superstep (exchange_apart: returns
-        the pre-exchange buckets as new_msg) + the separately-timed
-        all_to_all exchange stage."""
-        fn = make_superstep(program, plan, ec)
+        """The superstep and exchange for ``plan``/``ec``, the superstep
+        bound to the gather layouts in effect now."""
+        step, ex = sharded_superstep(program, plan, ec, mesh,
+                                     layout=layout is not None)
+        if layout is None:
+            return step, ex
+        return (lambda v, m, g, lay=layout: step(v, m, g, lay)), ex
 
-        # named, so the profiler names the programs ``jit_superstep`` and
-        # ``jit_exchange``, as the trace readers look for them
-        def superstep(v, m, g):
-            return fn(v, m, g, None, None)
-
-        # out_specs are written by hand: the body contains psums over the
-        # mesh axes, so eval_shape outside shard_map would fail on the
-        # unbound axis names
-        v_specs = jax.tree.map(lead, vert)
-        m_specs = MsgRel(dst=PSpec(axes, None),
-                         payload=PSpec(axes, None, None),
-                         valid=PSpec(axes, None))
-        g_specs = jax.tree.map(rep, init_gs(program.agg_dims))
-        bkt_specs = MsgRel(dst=PSpec(axes, None, None),
-                           payload=PSpec(axes, None, None, None),
-                           valid=PSpec(axes, None, None))
-        in_specs = (v_specs, m_specs, g_specs)
-        out_specs = (v_specs, bkt_specs, g_specs)
-        step = jax.jit(_shard_map(superstep, mesh, in_specs, out_specs))
-
-        @jax.named_scope(ROUTE)
-        def exchange(m: MsgRel) -> MsgRel:
-            r_dst, r_pay, r_val = connector.exchange_shard_map(
-                m.dst, m.payload, m.valid, axes)
-            P_l = m.dst.shape[0]
-            flat = lambda a: a.reshape((P_l, -1) + a.shape[3:])
-            return MsgRel(dst=flat(r_dst), payload=flat(r_pay),
-                          valid=flat(r_val))
-
-        ex = jax.jit(_shard_map(exchange, mesh, (bkt_specs,), m_specs))
-        return step, ex
-
-    step, exchange = build_step(plan, ec)
     if rgs is not None:
+        ahead = None
         gs = put_rep(rgs)
         vert = put_lead(vert)
         msg = put_lead(_regrow_msgs(rmsg, ec))
     else:
+        if ahead is None and i0 < max_supersteps:
+            # the first superstep's programs compile while the workers
+            # initialize and plan their layouts
+            ahead = _submit(_compile_first, program, plan, ec, mesh, vert)
         gs = init_gs(program.agg_dims)
         # place first: each worker initializes its own partitions
         # instead of the whole graph landing on the first device
@@ -330,6 +470,12 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
         gs = put_rep(gs)
         msg = put_lead(empty_msgs(P, ec.n_parts * ec.bucket_cap,
                                   program.msg_dims))
+    layout = plan_gather_layout(plan, vert, mesh)
+    step, exchange = build_step(plan, ec)
+    if ahead is not None:
+        step, exchange = _use_compiled(ahead, program, plan, ec,
+                                       (vert, msg, gs), layout, step,
+                                       exchange)
 
     n_live = (controller.g.n_vertices if controller is not None
               else int(jnp.sum(vert.vid >= 0)))
@@ -385,6 +531,7 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
             ex_stall = time.time() - t_ex
         m_exb.inc(ex_bytes)
         m_exs.inc(ex_stall)
+        trace.counter("exchange.bytes", ex_bytes)
         vert, gs = vert2, gs2
         i += 1
         with host_leg("readback", n):
@@ -419,6 +566,7 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
                         ec = dataclasses.replace(ec,
                                                  bucket_cap=need.bucket_cap)
                         msg = put_lead(_regrow_msgs(msg, ec))
+                    layout = plan_gather_layout(plan, vert, mesh)
                     step, exchange = build_step(plan, ec)
                     stats.append(coll.event(
                         i, "plan-switch", join=plan.join,
@@ -450,7 +598,9 @@ def run_sharded(vert: VertexRel, program: VertexProgram,
                 save_checkpoint(checkpoint_dir, i, vert, msg, gs)
         if on_superstep is not None:
             with host_leg("callback", n):
-                on_superstep(i, rec.as_dict())
+                stop = on_superstep(i, rec.as_dict()) is True
+            if stop:
+                break
         with host_leg("readback", n):
             halted = bool(gs.halt)
         if halted:

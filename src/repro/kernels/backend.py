@@ -86,23 +86,22 @@ def wants_edge_layout(plan) -> bool:
 def plan_edge_layout(edge_src, n_rows: int) -> np.ndarray:
     """Host-side gather layout for a (P, Ep) edge_src block over (P, n_rows)
     value rows: the csr_spmv kernel's work items, one int32 word each.
-    Partitions are flattened into ONE (P*Ep,) edge stream over P*n_rows
-    rows (global row = p * n_rows + source) — ``pallas_call`` must not be
-    vmapped (the batching rule would regrid the kernel and break its
-    sequential-carry assumption), so a single kernel invocation serves
-    the whole block. Uses ``plan_layout_fixed``: for edges sorted
-    by source within each partition, as ``load_graph`` leaves them, the
-    result shape depends only on the block's shape, so every equal-shape
-    super-partition yields an equal-shape layout and the OOC driver can
-    pass per-super-partition layouts through one shared jitted superstep
-    as traced arguments."""
-    from repro.kernels.csr_spmv.ops import plan_layout_fixed
-    edge_src = np.asarray(edge_src)
-    P, Ep = edge_src.shape
-    off = (np.arange(P, dtype=np.int64) * n_rows)[:, None]
-    flat = np.where(edge_src >= 0, edge_src + off, -1).reshape(-1)
-    return plan_layout_fixed(flat, P * n_rows, block_m=GATHER_BLOCK_M,
-                             block_r=GATHER_BLOCK_R)
+    The block is whatever one kernel call gathers: a one-device run's
+    whole relation, one out-of-core super-partition, or one worker's
+    partitions under ``run_sharded`` (planned per worker and stacked by
+    ``driver.plan_gather_layout``). Partitions are flattened into ONE
+    (P*Ep,) edge stream over P*n_rows rows (row = p * n_rows + source) —
+    ``pallas_call`` must not be vmapped (the batching rule would regrid
+    the kernel and break its sequential-carry assumption), so a single
+    kernel invocation serves the whole block. Uses ``plan_layout_fixed``:
+    for edges sorted by source within each partition, as ``load_graph``
+    leaves them, the result shape depends only on the block's shape, so
+    every equal-shape super-partition yields an equal-shape layout and
+    the OOC driver can pass per-super-partition layouts through one
+    shared jitted superstep as traced arguments."""
+    from repro.kernels.csr_spmv.ops import plan_rows_layout_fixed
+    return plan_rows_layout_fixed(edge_src, n_rows, block_m=GATHER_BLOCK_M,
+                                  block_r=GATHER_BLOCK_R)
 
 
 def edge_layout_shapes(P: int, Ep: int, n_rows: int):
@@ -115,6 +114,16 @@ def edge_layout_shapes(P: int, Ep: int, n_rows: int):
     return jax.ShapeDtypeStruct((cap,), jnp.int32)
 
 
+def pad_edge_layout(layout, n_items: int, P: int,
+                    n_rows: int) -> np.ndarray:
+    """``plan_edge_layout``'s layout for a (P, Ep) block over (P, n_rows)
+    value rows, padded to ``n_items`` items as ``plan_layout_fixed`` pads
+    (the last item repeated), so the layouts of equal-shape blocks
+    stack."""
+    from repro.kernels.csr_spmv.ops import pad_layout
+    return pad_layout(layout, n_items, P * n_rows, block_r=GATHER_BLOCK_R)
+
+
 def edge_layout_counts(layout, P: int, n_rows: int) -> Tuple[int, int]:
     """(real work items, tiles) of ``plan_edge_layout``'s layout for a
     (P, Ep) edge block over (P, n_rows) value rows."""
@@ -125,11 +134,14 @@ def edge_layout_counts(layout, P: int, n_rows: int) -> Tuple[int, int]:
 def edge_gather_values(values, edge_src, layout, *, impl_r: str):
     """Gather ``values[p, edge_src[p, e]]`` per edge via the csr_spmv
     one-hot matmul kernel, which writes in edge order. values: (P, Np, V);
-    edge_src: (P, Ep), -1 = invalid; layout from ``plan_edge_layout``.
-    Returns (P, Ep, V); invalid lanes read 0.0 (masked downstream by the
-    edge gate, exactly like the clip-gather's arbitrary row-0 reads on the
-    jnp path). The kernel reads edge_src itself: as it is for one
-    partition, with each partition's row offset added for several.
+    edge_src: (P, Ep), -1 = invalid; layout from ``plan_edge_layout`` of
+    this same block. Under ``run_sharded``'s shard_map the block is the
+    worker's own partitions and the layout its own row of the stacked
+    layouts: one kernel call per worker, on its device. Returns
+    (P, Ep, V); invalid lanes read 0.0 (masked downstream by the edge
+    gate, exactly like the clip-gather's arbitrary row-0 reads on the jnp
+    path). The kernel reads edge_src itself: as it is for one partition,
+    with each partition's row offset added for several.
 
     Bit-for-bit discipline: a finite value survives the one-hot matmul
     exactly (one 1.0*x product plus exact 0.0 additions; -0.0 may

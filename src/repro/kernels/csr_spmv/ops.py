@@ -40,6 +40,59 @@ def _plan_items(edge_src: np.ndarray, n_rows: int, block_m: int,
             (key % n_blocks).astype(np.int32))
 
 
+def _plan_items_rows(edge_src: np.ndarray, n_rows: int, block_m: int,
+                     block_r: int):
+    """``_plan_items`` of the (P*Ep,) stream that a (P, Ep) block flattens
+    to, row p over rows [p n_rows, (p+1) n_rows), for a block whose rows
+    each hold their valid sources sorted ahead of their pads (as
+    ``load_graph`` leaves them): a few int32 passes a row in place of the
+    flat stream's int64 keys. None for any other block."""
+    P, Ep = edge_src.shape
+    n_blocks = _n_blocks(P * n_rows, block_r)
+    wide = P * n_rows >= 1 << 31
+    keys = []
+    for p, row in enumerate(edge_src):
+        c = int(np.count_nonzero(row >= 0))
+        s = row[:c]
+        if (row[c:] >= 0).any() or not np.all(s[1:] >= s[:-1]):
+            return None
+        if not c:
+            continue
+        b = ((s.astype(np.int64) if wide else s) + p * n_rows) // block_r
+        first = np.empty(c, bool)      # an item starts here
+        first[0] = True
+        np.not_equal(b[1:], b[:-1], out=first[1:])
+        first[(-p * Ep) % block_m::block_m] = True
+        at = np.flatnonzero(first)
+        keys.append((p * Ep + at) // block_m * n_blocks + b[at])
+    key = np.concatenate(keys) if keys else np.zeros(0, np.int64)
+    # a row's first item may repeat the previous row's last
+    key = key[np.diff(key, prepend=-1) != 0]
+    n_tiles = _n_tiles(P * Ep, block_m)
+    has = np.zeros(n_tiles, bool)
+    has[key // n_blocks] = True
+    key = np.sort(np.concatenate([key, np.flatnonzero(~has) * n_blocks]))
+    return ((key // n_blocks).astype(np.int32),
+            (key % n_blocks).astype(np.int32))
+
+
+def plan_rows_layout_fixed(edge_src: np.ndarray, n_rows: int, *,
+                           block_m: int = 1024, block_r: int = 256):
+    """``plan_layout_fixed`` of the stream a (P, Ep) block flattens to,
+    row p's sources offset by p * n_rows (invalid: -1), over P * n_rows
+    rows."""
+    edge_src = np.asarray(edge_src)
+    P, Ep = edge_src.shape
+    items = _plan_items_rows(edge_src, n_rows, block_m, block_r)
+    if items is None:
+        off = (np.arange(P, dtype=np.int64) * n_rows)[:, None]
+        flat = np.where(edge_src >= 0, edge_src + off, -1).reshape(-1)
+        items = _plan_items(flat, P * n_rows, block_m, block_r)
+    cap = item_capacity(P * Ep, P * n_rows, block_m=block_m,
+                        block_r=block_r)
+    return _pad_items(*items, cap, _n_blocks(P * n_rows, block_r))
+
+
 def plan_layout(edge_src: np.ndarray, n_rows: int, *, block_m: int = 1024,
                 block_r: int = 256) -> np.ndarray:
     """Host-side plan of the kernel's work items. Tile t is edges
@@ -96,10 +149,22 @@ def plan_layout_fixed(edge_src: np.ndarray, n_rows: int, *,
     item_tile, item_row = _plan_items(edge_src, n_rows, block_m, block_r)
     cap = item_capacity(len(edge_src), n_rows, block_m=block_m,
                         block_r=block_r)
-    pad = max(cap - len(item_tile), 0)
+    return _pad_items(item_tile, item_row, cap, _n_blocks(n_rows, block_r))
+
+
+def _pad_items(item_tile, item_row, n_items: int, n_blocks: int):
+    pad = max(n_items - len(item_tile), 0)
     return pack_items(np.pad(item_tile, (0, pad), mode="edge"),
-                      np.pad(item_row, (0, pad), mode="edge"),
-                      _n_blocks(n_rows, block_r))
+                      np.pad(item_row, (0, pad), mode="edge"), n_blocks)
+
+
+def pad_layout(items, n_items: int, n_rows: int, *,
+               block_r: int = 256) -> np.ndarray:
+    """A layout's words padded to ``n_items`` items by repeating its last
+    item, as ``plan_layout_fixed`` pads (a word holds its item's lag
+    behind its tile, so the word itself cannot be repeated)."""
+    n_blocks = _n_blocks(n_rows, block_r)
+    return _pad_items(*unpack_items(items, n_blocks), n_items, n_blocks)
 
 
 def layout_counts(items, n_rows: int, *, block_r: int = 256) -> tuple:

@@ -291,3 +291,26 @@ def test_fused_pack_lowers_to_fewer_bytes_and_same_buckets():
         assert np.array_equal(a, b)
     assert bts[True] < bts[False], \
         f"fused leg must move fewer bytes: {bts[True]} vs {bts[False]}"
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_edge_capacity_pads_partitions_and_keeps_results(impl):
+    """``load_graph(edge_capacity=...)`` gives every partition that many
+    edge slots, its edges first and empty slots (-1, -1, 0.0) after, and
+    PageRank on the padded relation reads exactly what it reads on the
+    unpadded one, on both gather paths."""
+    vd = ALGOS["pagerank"][1]
+    tight = load_graph(EDGES, N, P=4, value_dims=vd)
+    Ep = tight.edge_src.shape[1]
+    padded = load_graph(EDGES, N, P=4, value_dims=vd, edge_capacity=Ep + 300)
+    assert padded.edge_src.shape == (4, Ep + 300)
+    for f, empty in (("edge_src", -1), ("edge_dst", -1), ("edge_val", 0.0)):
+        a, b = np.asarray(getattr(tight, f)), np.asarray(getattr(padded, f))
+        assert np.array_equal(b[:, :Ep], a) and (b[:, Ep:] == empty).all()
+    assert load_graph(EDGES, N, P=4, value_dims=vd,
+                      edge_capacity=Ep - 1).edge_src.shape == (4, Ep)
+    plan = _plan("pagerank", "full_outer", "partitioning", impl)
+    got = [gather_values(run_host(v, PageRank(N, iterations=6), plan,
+                                  max_supersteps=30).vertex, N)
+           for v in (tight, padded)]
+    assert np.array_equal(got[0], got[1])

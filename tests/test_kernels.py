@@ -188,3 +188,58 @@ def test_csr_spmv_layout_of_unsorted_stream_grows():
     cap = kbackend.edge_layout_shapes(1, Ep, Np).shape[0]
     items, _ = kbackend.edge_layout_counts(layout, 1, Np)
     assert layout.shape[0] == items > cap
+
+
+def test_csr_spmv_padded_layout_gathers_the_same():
+    """Blocks of equal shape, one sorted by source and one not (as
+    ``run_sharded``'s workers may hold), plan layouts of different
+    lengths; each, padded to the longest by repeating its last item,
+    stacks with the other and gathers exactly what it gathered
+    unpadded."""
+    from repro.kernels import backend as kbackend
+    Ep, Np, V = 4000, 3000, 2
+    blocks = [_edge_block(11, 1, Ep, Np, ordered) for ordered in (True,
+                                                                  False)]
+    layouts = [kbackend.plan_edge_layout(b, Np) for b in blocks]
+    assert len(layouts[0]) != len(layouts[1])
+    cap = max(len(x) for x in layouts)
+    vals = jnp.asarray(RNG.normal(size=(1, Np, V)).astype(np.float32))
+    for src, layout in zip(blocks, layouts):
+        padded = kbackend.pad_edge_layout(layout, cap, 1, Np)
+        assert padded.shape == (cap,)
+        assert kbackend.edge_layout_counts(padded, 1, Np) == \
+            kbackend.edge_layout_counts(layout, 1, Np)
+        got = [np.asarray(kbackend.edge_gather_values(
+            vals, jnp.asarray(src), jnp.asarray(x), impl_r="pallas"))
+            for x in (layout, padded)]
+        np.testing.assert_array_equal(got[0], got[1])
+
+
+@pytest.mark.parametrize("case", range(6))
+def test_csr_spmv_rows_plan_is_the_flat_streams_plan(case):
+    """The row-by-row plan of a (P, Ep) block (``plan_edge_layout``) is
+    item for item the plan of the flat stream it stands for, row p's
+    sources offset by p * n_rows: for rows sorted ahead of their pads,
+    rows that are empty or full, tiles and row blocks that rows straddle,
+    and (through the flat planner) a row out of order."""
+    rng = np.random.default_rng(case)
+    P, Ep, n_rows = [(1, 4000, 3000), (3, 700, 90), (4, 1025, 5000),
+                     (2, 64, 9), (5, 300, 700), (3, 500, 40)][case]
+    bm, br = [(1024, 256), (16, 8), (1024, 256), (8, 4), (32, 8),
+              (16, 4)][case]
+    src = np.full((P, Ep), -1, np.int32)
+    for p in range(P):
+        c = [Ep, 0, int(rng.integers(0, Ep + 1))][p % 3]
+        src[p, :c] = np.sort(rng.integers(0, n_rows, c))
+    if case == 5:
+        rng.shuffle(src[0])
+    off = (np.arange(P, dtype=np.int64) * n_rows)[:, None]
+    flat = np.where(src >= 0, src + off, -1).reshape(-1)
+    want = spmv_ops.plan_layout_fixed(flat, P * n_rows, block_m=bm,
+                                      block_r=br)
+    assert (spmv_ops._plan_items_rows(src, n_rows, bm, br) is None) == \
+        (case == 5)
+    got = spmv_ops.plan_rows_layout_fixed(src, n_rows, block_m=bm,
+                                          block_r=br)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
